@@ -36,6 +36,7 @@ from .findings import Finding
 # primitive names that host-sync a jitted graph when hit in the step loop
 _CALLBACK_PRIMS = (
     "debug_callback",
+    "debug_print",
     "pure_callback",
     "io_callback",
     "callback",
@@ -55,15 +56,13 @@ def _iter_eqns(jaxpr):
 
 
 def _sub_jaxprs(val):
-    import jax.core as jcore
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
-    closed = getattr(jcore, "ClosedJaxpr", None)
-    open_ = getattr(jcore, "Jaxpr", None)
     vals = val if isinstance(val, (tuple, list)) else (val,)
     for v in vals:
-        if closed is not None and isinstance(v, closed):
+        if isinstance(v, ClosedJaxpr):
             yield v.jaxpr
-        elif open_ is not None and isinstance(v, open_):
+        elif isinstance(v, Jaxpr):
             yield v
 
 

@@ -100,7 +100,7 @@ def plan_flash_attention(dims: Dims, config: Dict[str, int]) -> List[Plan]:
         return (b, h // g, j, 0)
 
     def rowmap(b, h, i, j):
-        return (b, h, i)
+        return (b, h, i, 0)
 
     def kvmap_t(b, h, j, i):
         return (b, h // g, j, 0)
@@ -109,13 +109,13 @@ def plan_flash_attention(dims: Dims, config: Dict[str, int]) -> List[Plan]:
         return (b, h, i, 0)
 
     def rowmap_t(b, h, j, i):
-        return (b, h, i)
+        return (b, h, i, 0)
 
     def outk_t(b, h, j, i):
         return (b, h, j, 0)
 
     q_arr, kv_arr = (B, Hq, Sq, D), (B, Hkv, Sk, D)
-    row_arr = (B, Hq, Sq)
+    row_arr = (B, Hq, Sq, 1)
     fwd = Plan(
         kernel="flash_attention_fwd",
         path=path,
@@ -125,7 +125,7 @@ def plan_flash_attention(dims: Dims, config: Dict[str, int]) -> List[Plan]:
             Block("k", kv_arr, (1, 1, bk, D), kvmap, dt),
             Block("v", kv_arr, (1, 1, bk, D), kvmap, dt),
             Block("o", q_arr, (1, 1, bq, D), qmap, dt),
-            Block("lse", row_arr, (1, 1, bq), rowmap, "float32"),
+            Block("lse", row_arr, (1, 1, bq, 1), rowmap, "float32"),
         ],
         scratch=[
             ("m", (bq, 1), "float32"),
@@ -147,8 +147,8 @@ def plan_flash_attention(dims: Dims, config: Dict[str, int]) -> List[Plan]:
             Block("k", kv_arr, (1, 1, bk, D), kvmap, dt),
             Block("v", kv_arr, (1, 1, bk, D), kvmap, dt),
             Block("do", q_arr, (1, 1, bq, D), qmap, dt),
-            Block("lse", row_arr, (1, 1, bq), rowmap, "float32"),
-            Block("delta", row_arr, (1, 1, bq), rowmap, "float32"),
+            Block("lse", row_arr, (1, 1, bq, 1), rowmap, "float32"),
+            Block("delta", row_arr, (1, 1, bq, 1), rowmap, "float32"),
             Block("dq", q_arr, (1, 1, bq, D), qmap, dt),
         ],
         scratch=[("acc", (bq, D), "float32")],
@@ -168,8 +168,8 @@ def plan_flash_attention(dims: Dims, config: Dict[str, int]) -> List[Plan]:
             Block("k", kv_arr, (1, 1, bk, D), kvmap_t, dt),
             Block("v", kv_arr, (1, 1, bk, D), kvmap_t, dt),
             Block("do", q_arr, (1, 1, bq, D), qmap_t, dt),
-            Block("lse", row_arr, (1, 1, bq), rowmap_t, "float32"),
-            Block("delta", row_arr, (1, 1, bq), rowmap_t, "float32"),
+            Block("lse", row_arr, (1, 1, bq, 1), rowmap_t, "float32"),
+            Block("delta", row_arr, (1, 1, bq, 1), rowmap_t, "float32"),
             Block("dk", dkv_arr, (1, 1, bk, D), outk_t, "float32"),
             Block("dv", dkv_arr, (1, 1, bk, D), outk_t, "float32"),
         ],
@@ -198,7 +198,7 @@ def plan_rwkv6(dims: Dims, config: Dict[str, int]) -> List[Plan]:
         return (b, h, i, 0)
 
     def umap(b, h, i):
-        return (h, 0)
+        return (h, 0, 0)
 
     def statemap(b, h, i):
         return (b, h, 0, 0)
@@ -213,7 +213,7 @@ def plan_rwkv6(dims: Dims, config: Dict[str, int]) -> List[Plan]:
                 Block("k", (B, H, T, K), (1, 1, c, K), seqmap, dt),
                 Block("v", (B, H, T, V), (1, 1, c, V), seqmap, dt),
                 Block("ld", (B, H, T, K), (1, 1, c, K), seqmap, dt),
-                Block("u", (H, K), (1, K), umap, "float32"),
+                Block("u", (H, 1, K), (1, 1, K), umap, "float32"),
                 Block("o", (B, H, T, V), (1, 1, c, V), seqmap, dt),
                 Block("state", (B, H, K, V), (1, 1, K, V), statemap, "float32"),
             ],
@@ -273,7 +273,6 @@ def plan_paged_attention(dims: Dims, config: Dict[str, int]) -> List[Plan]:
                 "paged_attention", path, f"Hq={Hq} not divisible by Hkv={Hkv}"
             )
         ]
-    g = Hq // Hkv
     # the resolver clamps to [1, npag]; model the same so the checker
     # judges the tiling that would actually run
     ppb = max(1, min(int(config["pages_per_block"]), npag))
@@ -284,35 +283,42 @@ def plan_paged_attention(dims: Dims, config: Dict[str, int]) -> List[Plan]:
     btab = np.zeros((B, nb * ppb), dtype=np.int64)
     btab[:, :npag] = P - 1
 
-    def qmap(b, h, j):
-        return (b, h, 0, 0)
+    def qmap(b, j):
+        return (b, 0, 0)
 
     def kvmap(p):
-        def index_map(b, h, j, p=p):
-            return (int(btab[b, j * ppb + p]), 0, h, 0)
+        def index_map(b, j, p=p):
+            return (int(btab[b, j * ppb + p]), 0, 0, 0)
 
         return index_map
 
     pages_arr = (P, ps, Hkv, D)
-    blocks = [Block("q", (B, Hkv, g, D), (1, 1, g, D), qmap, dt)]
+    blocks = [Block("q", (B, Hq, D), (1, Hq, D), qmap, dt)]
     for side in ("k", "v"):
         for p in range(ppb):
             blocks.append(
-                Block(f"{side}_pages[{p}]", pages_arr, (1, ps, 1, D), kvmap(p), dt)
+                Block(f"{side}_pages[{p}]", pages_arr, (1, ps, Hkv, D), kvmap(p), dt)
             )
-    blocks.append(Block("o", (B, Hkv, g, D), (1, 1, g, D), qmap, dt))
+    blocks.append(Block("o", (B, Hq, D), (1, Hq, D), qmap, dt))
+    # every query head scores against the whole flattened page
+    cols = ps * Hkv
     return [
         Plan(
             kernel="paged_attention_fwd",
             path=path,
-            grid=(B, Hkv, nb),
+            grid=(B, nb),
             blocks=blocks,
             scratch=[
-                ("m", (g, 1), "float32"),
-                ("l", (g, 1), "float32"),
-                ("acc", (g, D), "float32"),
+                ("m", (Hq, 1), "float32"),
+                ("l", (Hq, 1), "float32"),
+                ("acc", (Hq, D), "float32"),
             ],
-            implicit=[("s", (g, ps), "float32"), ("pe", (g, ps), "float32")],
+            implicit=[
+                ("k", (cols, D), "float32"),
+                ("v", (cols, D), "float32"),
+                ("s", (Hq, cols), "float32"),
+                ("pe", (Hq, cols), "float32"),
+            ],
         )
     ]
 

@@ -289,7 +289,7 @@ def _bwd_jitted(causal, window, block_q, block_k):
 
     return jax.jit(functools.partial(
         flash_attention_bwd, causal=causal, window=window, block_q=block_q,
-        block_k=block_k, interpret=ops.INTERPRET))
+        block_k=block_k, interpret=ops.interpret_mode()))
 
 
 def _bwd_call(q, k, v, o, lse, do, *, causal, window, block_q, block_k):
@@ -316,7 +316,7 @@ def tune_flash_attention_bwd(q, k, v, *, causal: bool = True,
     cands, rej, dflt = attention_candidates(Sq, Sk, D, q.dtype.itemsize,
                                             vmem_budget)
     o, lse = flash_attention_fwd(q, k, v, causal=causal, window=window,
-                                 interpret=ops.INTERPRET, return_lse=True)
+                                 interpret=ops.interpret_mode(), return_lse=True)
     do = jax.numpy.ones_like(o)
 
     def make_fn(block_q: int, block_k: int):

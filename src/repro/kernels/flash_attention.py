@@ -30,10 +30,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import tuning
 
-# jax < 0.5 ships this as TPUCompilerParams; newer releases renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -104,8 +100,7 @@ def _attn_kernel_lse(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
     @pl.when(ik == nk - 1)
     def _emit_lse():
-        lse_ref[0, 0] = (m_scr[...]
-                         + jnp.log(jnp.maximum(l_scr[...], 1e-30)))[:, 0]
+        lse_ref[0, 0] = m_scr[...] + jnp.log(jnp.maximum(l_scr[...], 1e-30))
 
 
 def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
@@ -113,7 +108,11 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
                         block_k: int | None = None,
                         interpret: bool = False, return_lse: bool = False):
     """q: (B, Sq, Hq, D); k/v: (B, Sk, Hkv, D) -> (B, Sq, Hq, D)
-    [, lse (B, Hq, Sq)]. block_q/block_k None = auto (tuned cache)."""
+    [, lse (B, Hq, Sq, 1)]. block_q/block_k None = auto (tuned cache).
+
+    The logsumexp rows keep a trailing unit dim so their block
+    ``(1, 1, bq, 1)`` meets Mosaic's rule for the last two block dims
+    (multiples of (8, 128) or equal to the array's own dims)."""
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
     block_q, block_k = tuning.resolve_attention_blocks(
@@ -143,7 +142,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
         pltpu.VMEM((bq, 1), jnp.float32),
         pltpu.VMEM((bq, D), jnp.float32),
     ]
-    params = _CompilerParams(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
     o_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0))
     if not return_lse:
@@ -155,13 +154,13 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
             interpret=interpret,
         )(qt, kt, vt)
         return out.transpose(0, 2, 1, 3)
+    lse_spec = pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0))
     out, lse = pl.pallas_call(
         functools.partial(_attn_kernel_lse, **kw),
         grid=(B, Hq, nq, nk), in_specs=in_specs,
-        out_specs=[o_spec,
-                   pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i))],
+        out_specs=[o_spec, lse_spec],
         out_shape=[jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
-                   jax.ShapeDtypeStruct((B, Hq, Sq), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, Hq, Sq, 1), jnp.float32)],
         scratch_shapes=scratch, compiler_params=params,
         interpret=interpret,
     )(qt, kt, vt)
@@ -199,15 +198,15 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]
+        lse = lse_ref[0, 0]                                  # (bq, 1)
         delta = delta_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         mask = _block_mask_iota(q_lo, k_lo, bq, bk, causal, window)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         acc_scr[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -243,18 +242,18 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]
+        lse = lse_ref[0, 0]                                  # (bq, 1)
         delta = delta_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         mask = _block_mask_iota(q_lo, k_lo, bq, bk, causal, window)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         dv_scr[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
+        ds = p * (dp - delta)
         dk_scr[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -274,8 +273,9 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool = True,
                         window: int = 0, block_q: int | None = None,
                         block_k: int | None = None,
                         interpret: bool = False):
-    """Returns (dq, dk, dv) with q/k/v in (B, S, H, D) layout.
-    block_q/block_k None = auto (tuned cache)."""
+    """Returns (dq, dk, dv) with q/k/v in (B, S, H, D) layout and lse as
+    the forward returns it, (B, Hq, Sq, 1). block_q/block_k None = auto
+    (tuned cache)."""
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
     block_q, block_k = tuning.resolve_attention_blocks(
@@ -288,11 +288,11 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool = True,
     qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
     dot_, ot = do.transpose(0, 2, 1, 3), o.transpose(0, 2, 1, 3)
     delta = jnp.sum(dot_.astype(jnp.float32) * ot.astype(jnp.float32),
-                    axis=-1)                                   # (B,Hq,Sq)
+                    axis=-1, keepdims=True)                    # (B,Hq,Sq,1)
     kw = dict(scale=scale, causal=causal, window=window, bq=bq, bk=bk)
-    params = _CompilerParams(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
-    row_spec = pl.BlockSpec((1, 1, bq), lambda b, h, i, j: (b, h, i))
+    row_spec = pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, j: (b, h, i, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, nk=nk, **kw),
         grid=(B, Hq, nq, nk),
@@ -309,7 +309,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool = True,
         compiler_params=params, interpret=interpret,
     )(qt, kt, vt, dot_, lse, delta)
 
-    row_spec2 = pl.BlockSpec((1, 1, bq), lambda b, h, j, i: (b, h, i))
+    row_spec2 = pl.BlockSpec((1, 1, bq, 1), lambda b, h, j, i: (b, h, i, 0))
     dk_h, dv_h = pl.pallas_call(
         functools.partial(_dkv_kernel, nq=nq, **kw),
         grid=(B, Hq, nk, nq),

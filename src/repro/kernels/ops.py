@@ -1,8 +1,11 @@
 """Jitted public wrappers around the Pallas kernels.
 
-On this CPU container the kernels execute in interpret mode (the kernel
-body runs as Python/XLA on CPU); on TPU `interpret=False` compiles real
-Mosaic kernels. The model layer selects these via backend='pallas'.
+Each call decides its mode from the platform it runs on
+(:func:`interpret_mode`): on a TPU the kernels compile to Mosaic and
+never interpret; on any other backend the kernel body runs in Pallas
+interpret mode, the only way it can run there. Nothing is decided when
+the module is imported, so importing it touches no device. The model
+layer selects these via backend='pallas'.
 
 Tile parameters default to ``None`` ("auto"): each wrapper resolves them
 *eagerly* through the tuned-config cache (:mod:`repro.kernels.tuning`,
@@ -27,38 +30,40 @@ from repro.kernels.paged_attention import paged_attention_fwd
 from repro.kernels.rmsnorm import rmsnorm_fwd
 from repro.kernels.rwkv6 import wkv6_fwd
 
-_ON_TPU = any(d.platform == "tpu" for d in jax.devices())
-INTERPRET = not _ON_TPU
+def interpret_mode() -> bool:
+    """Whether a kernel call made now runs in interpret mode: False on a
+    TPU backend, True everywhere else."""
+    return jax.default_backend() != "tpu"
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_attention(q, k, v, causal, window, block_q, block_k,
-                     bwd_block_q, bwd_block_k):
+                     bwd_block_q, bwd_block_k, interpret):
     return flash_attention_fwd(q, k, v, causal=causal, window=window,
                                block_q=block_q, block_k=block_k,
-                               interpret=INTERPRET)
+                               interpret=interpret)
 
 
 def _fa_fwd(q, k, v, causal, window, block_q, block_k, bwd_block_q,
-            bwd_block_k):
+            bwd_block_k, interpret):
     o, lse = flash_attention_fwd(q, k, v, causal=causal, window=window,
                                  block_q=block_q, block_k=block_k,
-                                 interpret=INTERPRET, return_lse=True)
+                                 interpret=interpret, return_lse=True)
     return o, (q, k, v, o, lse)
 
 
 def _fa_bwd(causal, window, block_q, block_k, bwd_block_q, bwd_block_k,
-            res, do):
+            interpret, res, do):
     q, k, v, o, lse = res
     return flash_attention_bwd(q, k, v, o, lse, do, causal=causal,
                                window=window, block_q=bwd_block_q,
-                               block_k=bwd_block_k, interpret=INTERPRET)
+                               block_k=bwd_block_k, interpret=interpret)
 
 
 _flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 _flash_attention_jit = jax.jit(_flash_attention,
-                               static_argnums=(3, 4, 5, 6, 7, 8))
+                               static_argnums=(3, 4, 5, 6, 7, 8, 9))
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -75,12 +80,13 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         block_q, block_k, q_shape=q.shape, k_shape=k.shape, dtype=q.dtype,
         causal=causal, window=window, kernel="flash_attention_bwd")
     return _flash_attention_jit(q, k, v, causal, window, bq, bk, bq_b,
-                                bk_b)
+                                bk_b, interpret_mode())
 
 
-@partial(jax.jit, static_argnames=("chunk",))
-def _wkv6_jit(q, k, v, ld, u=None, initial_state=None, *, chunk: int = 64):
-    o, state = wkv6_fwd(q, k, v, ld, u, chunk=chunk, interpret=INTERPRET)
+@partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _wkv6_jit(q, k, v, ld, u=None, initial_state=None, *, chunk: int,
+              interpret: bool):
+    o, state = wkv6_fwd(q, k, v, ld, u, chunk=chunk, interpret=interpret)
     if initial_state is not None:
         # contribution of the carried-in state: q'_t @ (decay_t . S0)
         f32 = jnp.float32
@@ -103,15 +109,16 @@ def wkv6(q, k, v, ld, u=None, initial_state=None, *,
     c = tuning.resolve_wkv_chunk(chunk, q_shape=q.shape,
                                  v_head=v.shape[-1], dtype=q.dtype,
                                  use_u=u is not None)
-    return _wkv6_jit(q, k, v, ld, u, initial_state, chunk=c)
+    return _wkv6_jit(q, k, v, ld, u, initial_state, chunk=c,
+                     interpret=interpret_mode())
 
 
-@partial(jax.jit, static_argnames=("pages_per_block",))
+@partial(jax.jit, static_argnames=("pages_per_block", "interpret"))
 def _paged_attention_jit(q, k_pages, v_pages, block_tables, lengths, *,
-                         pages_per_block: int = 1):
+                         pages_per_block: int, interpret: bool):
     return paged_attention_fwd(q, k_pages, v_pages, block_tables, lengths,
                                pages_per_block=pages_per_block,
-                               interpret=INTERPRET)
+                               interpret=interpret)
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
@@ -122,13 +129,14 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
         pages_per_block, q_shape=q.shape, pages_shape=k_pages.shape,
         n_pages=block_tables.shape[1], dtype=q.dtype)
     return _paged_attention_jit(q, k_pages, v_pages, block_tables, lengths,
-                                pages_per_block=ppb)
+                                pages_per_block=ppb,
+                                interpret=interpret_mode())
 
 
-@partial(jax.jit, static_argnames=("eps", "block_rows"))
-def _rmsnorm_jit(x, scale, *, eps: float = 1e-5, block_rows: int = 256):
+@partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
+def _rmsnorm_jit(x, scale, *, eps: float, block_rows: int, interpret: bool):
     return rmsnorm_fwd(x, scale, eps=eps, block_rows=block_rows,
-                       interpret=INTERPRET)
+                       interpret=interpret)
 
 
 def rmsnorm(x, scale, *, eps: float = 1e-5, block_rows: int | None = None):
@@ -136,4 +144,5 @@ def rmsnorm(x, scale, *, eps: float = 1e-5, block_rows: int | None = None):
     br = tuning.resolve_rmsnorm_rows(
         block_rows, rows=int(np.prod(x.shape[:-1], dtype=np.int64)),
         d=x.shape[-1], dtype=x.dtype)
-    return _rmsnorm_jit(x, scale, eps=eps, block_rows=br)
+    return _rmsnorm_jit(x, scale, eps=eps, block_rows=br,
+                        interpret=interpret_mode())

@@ -8,13 +8,15 @@ operand, and each K/V BlockSpec's ``index_map`` reads the table to pick
 the physical page its DMA fetches — the pool never has to be gathered
 into a contiguous activation on the host side.
 
-Tiling: grid ``(B, Hkv, n_blocks)`` with the page-block dim innermost
-and sequential ("arbitrary"), so the online-softmax accumulators live in
-VMEM scratch across page blocks. The tunable tile parameter is
-``pages_per_block``: how many pages one grid step consumes. It is
-realised by passing the pool ``pages_per_block`` times with offset
-index maps — each copy is an independent page DMA the pipeline keeps in
-flight, so larger values trade VMEM for fewer grid steps. Like every
+Tiling: grid ``(B, n_blocks)`` with the page-block dim innermost and
+sequential ("arbitrary"), so the online-softmax accumulators live in
+VMEM scratch across page blocks. Each K/V block is one whole page with
+all its KV heads, ``(1, page_size, Hkv, D)``, so one DMA moves a page.
+The tunable tile parameter is ``pages_per_block``: how many pages one
+grid step consumes. It is realised by passing the pool
+``pages_per_block`` times with offset index maps — each copy is an
+independent page DMA the pipeline keeps in flight, so larger values
+trade VMEM for fewer grid steps. Like every
 other kernel, ``pages_per_block=None`` means "auto": resolved from the
 tuned-config cache (:mod:`repro.kernels.tuning`, populated by
 ``python -m benchmarks.run --tune``), default 1.
@@ -36,21 +38,24 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import tuning
 
-# jax < 0.5 ships this as TPUCompilerParams; newer releases renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
 def _paged_kernel(btab_ref, len_ref, q_ref, *refs, scale: float, ps: int,
-                  ppb: int, nb: int, g: int):
-    """refs = k_ref x ppb, v_ref x ppb, o_ref, m_scr, l_scr, acc_scr."""
+                  ppb: int, nb: int, hkv: int, g: int):
+    """refs = k_ref x ppb, v_ref x ppb, o_ref, m_scr, l_scr, acc_scr.
+
+    A K/V block is one whole page, every KV head: (1, ps, Hkv, D). It is
+    flattened to (ps * Hkv, D) rows, row c holding token c // Hkv of KV
+    head c % Hkv, and all Hq query heads score against all rows in one
+    matmul; a query head keeps only the rows of its own KV head. That
+    spends Hkv times the minimum FLOPs on a step that is bound by the
+    page's bytes, and needs no per-head slicing of the block."""
     k_refs = refs[:ppb]
     v_refs = refs[ppb:2 * ppb]
     o_ref, m_scr, l_scr, acc_scr = refs[2 * ppb:]
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -59,18 +64,22 @@ def _paged_kernel(btab_ref, len_ref, q_ref, *refs, scale: float, ps: int,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     length = len_ref[b]
-    q = q_ref[0, 0].astype(jnp.float32) * scale              # (g, D)
+    hq = hkv * g
+    q = q_ref[0].astype(jnp.float32) * scale                 # (Hq, D)
+    row = jax.lax.broadcasted_iota(jnp.int32, (hq, ps * hkv), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (hq, ps * hkv), 1)
+    own_head = (col % hkv) == (row // g)
     for p in range(ppb):
         page_start = (j * ppb + p) * ps                      # logical pos
 
         def _consume(p=p, page_start=page_start):
-            k = k_refs[p][0, :, 0, :].astype(jnp.float32)    # (ps, D)
-            v = v_refs[p][0, :, 0, :].astype(jnp.float32)
+            D = q.shape[-1]
+            k = k_refs[p][0].astype(jnp.float32).reshape(ps * hkv, D)
+            v = v_refs[p][0].astype(jnp.float32).reshape(ps * hkv, D)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
-            kpos = page_start + jax.lax.broadcasted_iota(
-                jnp.int32, (g, ps), 1)
-            s = jnp.where(kpos < length, s, NEG_INF)
+            kpos = page_start + col // hkv
+            s = jnp.where(own_head & (kpos < length), s, NEG_INF)
             m_prev = m_scr[...]
             m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
             pe = jnp.exp(s - m_new)
@@ -85,8 +94,8 @@ def _paged_kernel(btab_ref, len_ref, q_ref, *refs, scale: float, ps: int,
 
     @pl.when(j == nb - 1)
     def _finish():
-        o_ref[0, 0] = (acc_scr[...] /
-                       jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] /
+                    jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_attention_fwd(q, k_pages, v_pages, block_tables, lengths, *,
@@ -113,39 +122,38 @@ def paged_attention_fwd(q, k_pages, v_pages, block_tables, lengths, *,
     if pad:
         btab = jnp.pad(btab, ((0, 0), (0, pad)))      # null-page padding
     lengths = jnp.asarray(lengths, jnp.int32).reshape(B)
-    qg = q.reshape(B, Hkv, g, D)              # GQA groups as a row tile
 
-    def q_map(b, h, j, bt, ln):
-        return (b, h, 0, 0)
+    def q_map(b, j, bt, ln):
+        return (b, 0, 0)
 
     def kv_map(p):
         # the in-kernel gather: physical page id straight from the table
-        def index_map(b, h, j, bt, ln, p=p):
-            return (bt[b, j * ppb + p], 0, h, 0)
+        def index_map(b, j, bt, ln, p=p):
+            return (bt[b, j * ppb + p], 0, 0, 0)
         return index_map
 
-    in_specs = [pl.BlockSpec((1, 1, g, D), q_map)]
-    in_specs += [pl.BlockSpec((1, ps, 1, D), kv_map(p)) for p in range(ppb)]
-    in_specs += [pl.BlockSpec((1, ps, 1, D), kv_map(p)) for p in range(ppb)]
+    # whole pages, all KV heads: the last two block dims equal the pool's
+    # (Hkv, D), which Mosaic accepts for any head count
+    kv_spec = [pl.BlockSpec((1, ps, Hkv, D), kv_map(p)) for p in range(ppb)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, nb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, D), q_map),
+        grid=(B, nb),
+        in_specs=[pl.BlockSpec((1, Hq, D), q_map), *kv_spec, *kv_spec],
+        out_specs=pl.BlockSpec((1, Hq, D), q_map),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, D), jnp.float32),
+            pltpu.VMEM((Hq, 1), jnp.float32),
+            pltpu.VMEM((Hq, 1), jnp.float32),
+            pltpu.VMEM((Hq, D), jnp.float32),
         ],
     )
     kern = functools.partial(_paged_kernel, scale=1.0 / np.sqrt(D), ps=ps,
-                             ppb=ppb, nb=nb, g=g)
-    kv = (k_pages.reshape(P, ps, Hkv, D), v_pages.reshape(P, ps, Hkv, D))
+                             ppb=ppb, nb=nb, hkv=Hkv, g=g)
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, g, D), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(btab, lengths, qg, *([kv[0]] * ppb), *([kv[1]] * ppb))
+    )(btab, lengths, q.reshape(B, Hq, D), *([k_pages] * ppb),
+      *([v_pages] * ppb))
     return out.reshape(B, 1, Hq, D)

@@ -33,10 +33,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import tuning
 
-# jax < 0.5 ships this as TPUCompilerParams; newer releases renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 # Largest in-chunk |cumsum(ld)| for which the decay-rescaled matmul path
 # is used: factors stay <= exp(30) ~ 1e13, far from f32 overflow even
 # after the (masked-out) upper-triangle products and the K-dim reduction.
@@ -57,13 +53,18 @@ def _wkv_kernel(q_ref, k_ref, v_ref, ld_ref, u_ref, o_ref, state_out_ref,
     v = v_ref[0, 0].astype(jnp.float32)          # (c, V)
     ld = ld_ref[0, 0].astype(jnp.float32)        # (c, K)
 
-    p_inc = jnp.cumsum(ld, axis=0)
+    t_i = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    s_i = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    # Mosaic has no cumsum: the in-chunk prefix sum is a lower-triangular
+    # ones matmul, at full f32 precision so the decays stay exact
+    p_inc = jax.lax.dot_general(
+        (t_i >= s_i).astype(jnp.float32), ld, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     p_exc = p_inc - ld
     w_exp = p_exc if use_u else p_inc
 
     # intra-chunk attention A[t,s] = q_t . (k_s exp(w_t - p_s)), s <(=) t
-    t_i = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    s_i = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
     mask = (t_i > s_i) if use_u else (t_i >= s_i)
 
     def _intra_matmul(_):
@@ -76,10 +77,14 @@ def _wkv_kernel(q_ref, k_ref, v_ref, ld_ref, u_ref, o_ref, state_out_ref,
         return jnp.where(mask, a, 0.0)
 
     def _intra_pairwise(_):
-        # masked fallback: exact per-pair decay, (c, c, K) tensor in VMEM
-        diff = w_exp[:, None, :] - p_inc[None, :, :]          # (c, c, K)
-        diff = jnp.where(mask[:, :, None], diff, -jnp.inf)
-        return jnp.einsum("tk,sk,tsk->ts", q, k, jnp.exp(diff))
+        # masked fallback: exact per-pair decay, (c, c, K) tensor in VMEM.
+        # Unmasked pairs have w_t <= p_s, so clamping at 0 changes none of
+        # them and keeps the masked ones finite; the mask is applied on
+        # (c, c) and the product reduced by hand (Mosaic has neither a
+        # batched dot_general nor a reshape of a boolean vector)
+        diff = jnp.minimum(w_exp[:, None, :] - p_inc[None, :, :], 0.0)
+        a = jnp.sum(q[:, None, :] * k[None, :, :] * jnp.exp(diff), axis=-1)
+        return jnp.where(mask, a, 0.0)
 
     # p_inc is a cumsum of ld <= 0, so -min(p_inc) is the chunk's largest
     # decay magnitude; beyond SAFE_DECAY_RANGE exp(-p_inc) would overflow
@@ -89,8 +94,8 @@ def _wkv_kernel(q_ref, k_ref, v_ref, ld_ref, u_ref, o_ref, state_out_ref,
                             (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     if use_u:
-        u = u_ref[0].astype(jnp.float32)                      # (K,)
-        diag = jnp.sum(q * u[None, :] * k, axis=1, keepdims=True)
+        u = u_ref[0].astype(jnp.float32)                      # (1, K)
+        diag = jnp.sum(q * u * k, axis=1, keepdims=True)
         o = o + diag * v
 
     # cross-chunk state contribution + recurrence
@@ -125,6 +130,9 @@ def wkv6_fwd(q, k, v, ld, u=None, *, chunk: int | None = None,
     use_u = u is not None
     if u is None:
         u = jnp.zeros((H, K), jnp.float32)
+    # (H, 1, K): the unit dim keeps the (1, 1, K) block within Mosaic's
+    # rule for the last two block dims
+    u = u.reshape(H, 1, K)
 
     def tr(x):
         return x.transpose(0, 2, 1, 3)    # (B, H, T, *)
@@ -138,7 +146,7 @@ def wkv6_fwd(q, k, v, ld, u=None, *, chunk: int | None = None,
             pl.BlockSpec((1, 1, c, K), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, c, V), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, c, K), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, K), lambda b, h, i: (h, 0)),
+            pl.BlockSpec((1, 1, K), lambda b, h, i: (h, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, c, V), lambda b, h, i: (b, h, i, 0)),
@@ -149,7 +157,7 @@ def wkv6_fwd(q, k, v, ld, u=None, *, chunk: int | None = None,
             jax.ShapeDtypeStruct((B, H, K, V), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tr(q), tr(k), tr(v), tr(ld), u)

@@ -2,13 +2,6 @@
 
 A function, not a module-level constant, so importing this module never
 touches jax device state (jax locks the device count on first init).
-
-Also the jax-version compat seam: newer jax spells the ambient-mesh
-context ``jax.set_mesh`` and takes ``axis_types`` in ``jax.make_mesh``;
-older releases (<= 0.4.x) have neither, but ``Mesh`` itself is a context
-manager with the same ambient-mesh effect. Callers use :func:`set_mesh`
-and :func:`make_mesh` from this module and never touch ``jax.set_mesh``
-directly.
 """
 from __future__ import annotations
 
@@ -33,12 +26,17 @@ def host_device_env(n_devices: int,
     survive into the child. The parent's own device count is untouched —
     jax locks it on first init, which is why multi-device measurement is
     subprocess-spawned at all (see bench/runner.run_with_devices).
+
+    The child is pinned to the CPU (``JAX_PLATFORMS=cpu``): it is a
+    sharding rehearsal on host devices, and on a machine with an
+    accelerator it must not reach for a chip the parent may hold.
     """
     env = dict(os.environ if base_env is None else base_env)
     flags = [f for f in env.get("XLA_FLAGS", "").split()
              if not f.startswith(_DEVICE_COUNT_FLAG)]
     flags.append(f"{_DEVICE_COUNT_FLAG}={n_devices}")
     env["XLA_FLAGS"] = " ".join(flags)
+    env["JAX_PLATFORMS"] = "cpu"
     return env
 
 
@@ -51,20 +49,15 @@ def simulated_device_count(env: Optional[Dict[str, str]] = None
     return int(m.group(1)) if m else None
 
 
-def _mk(shape, axes):
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):  # jax < 0.5: no axis_types
-        return jax.make_mesh(shape, axes)
+def _mk(shape, axes, devices=None):
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices)
 
 
 def set_mesh(mesh):
     """Context manager installing ``mesh`` as the ambient mesh."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh  # jax < 0.5: Mesh is its own context manager
+    return jax.set_mesh(mesh)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -80,6 +73,7 @@ def mesh_config(*, multi_pod: bool = False) -> MeshConfig:
     return MeshConfig(shape=(16, 16), axes=("data", "model"))
 
 
-def make_mesh(mesh_cfg: MeshConfig):
-    """Build a jax Mesh for an arbitrary MeshConfig (tests use small ones)."""
-    return _mk(mesh_cfg.shape, mesh_cfg.axes)
+def make_mesh(mesh_cfg: MeshConfig, devices=None):
+    """Build a jax Mesh for an arbitrary MeshConfig (tests use small ones)
+    over ``devices`` (default: all of them)."""
+    return _mk(mesh_cfg.shape, mesh_cfg.axes, devices)

@@ -13,6 +13,11 @@ radix cache, and `--num-sessions N --turns T` swaps the Poisson request
 stream for a multi-turn session-replay workload (each turn arrives with
 its accumulated history — the pattern prefix sharing accelerates).
 
+Sizes: by default the model is a reduced config; `--full` keeps every
+published width and `--layers` cuts only the depth. `--attention-backend
+pallas` runs the Pallas kernels (the paged decode kernel on the paged
+schedulers).
+
 `--scheduler disaggregated` runs the paged model path under separate
 prefill and decode worker pools over one shared page pool
 (`--prefill-workers N --decode-workers M`); the report gains per-role
@@ -29,6 +34,7 @@ under a deterministic fault-injection schedule (see
 from __future__ import annotations
 
 import argparse
+import dataclasses
 
 import numpy as np
 
@@ -36,6 +42,7 @@ import jax
 
 from repro.configs import RunConfig, ShapeConfig, get_arch, reduced
 from repro.data.pipeline import synth_requests, synth_sessions
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh, set_mesh
 from repro.runtime.elastic import choose_mesh
 from repro.runtime.steps import build_serve_steps
@@ -66,6 +73,7 @@ def apply_slo(requests, *, deadline_s: float = 0.0,
 def build_engine(arch: str, *, batch: int, prompt_len: int,
                  max_new_tokens: int, scheduler: str = "continuous",
                  use_reduced: bool = True, reduce_kw=None,
+                 layers: int = 0, attention_backend: str = "dense",
                  greedy: bool = True, eos_id=None, seed: int = 0,
                  clock=None, page_size: int = 16, num_pages=None,
                  prefill_chunk_tokens: int = 0,
@@ -75,7 +83,8 @@ def build_engine(arch: str, *, batch: int, prompt_len: int,
     """Build a serving engine for ``arch`` (the launcher's plumbing,
     importable so benchmarks and tests share it). ``reduce_kw`` overrides
     the reduction sizes (layers/d_model/vocab/d_ff — the benchmarks use a
-    smaller cell than the CLI default). For ``scheduler="paged"`` the
+    smaller cell than the CLI default). ``layers`` (0 = keep) sets the
+    depth of an unreduced config. For ``scheduler="paged"`` the
     engine is wired to the model's paged triple (chunked prefill + the
     block-table decode path) and ``page_size``/``num_pages``/
     ``prefill_chunk_tokens``/``prefix_cache`` apply. Returns
@@ -83,12 +92,14 @@ def build_engine(arch: str, *, batch: int, prompt_len: int,
     cfg = get_arch(arch)
     if use_reduced:
         cfg = reduced(cfg, **(reduce_kw or {}))
+    elif layers:
+        cfg = dataclasses.replace(cfg, num_layers=layers)
     span = prompt_len + max_new_tokens
     mesh_cfg = choose_mesh(jax.device_count())
     shape = ShapeConfig("serve", "decode", span, batch)
     rcfg = RunConfig(model=cfg, shape=shape, mesh=mesh_cfg,
-                     attention_backend="dense", param_dtype="float32",
-                     decode_attention="simple")
+                     attention_backend=attention_backend,
+                     param_dtype="float32", decode_attention="simple")
     mesh = make_mesh(mesh_cfg)
     with set_mesh(mesh):
         prefill_fn, decode_fn, model = build_serve_steps(rcfg)
@@ -167,8 +178,16 @@ def main(argv=None):
     ap.add_argument("--sample", action="store_true",
                     help="sample tokens instead of greedy argmax")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--full", action="store_true",
+                    help="keep every published width; --layers then sets "
+                         "only the depth")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="layer count (0 = 2 reduced, the published count "
+                         "with --full)")
+    ap.add_argument("--attention-backend",
+                    choices=("dense", "chunked", "pallas"), default="dense")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # session replay grows each turn's prompt by its history; size the
     # span (and block tables) for the longest final-turn prompt
@@ -182,7 +201,10 @@ def main(argv=None):
     engine, cfg = build_engine(
         args.arch, batch=args.batch, prompt_len=prompt_len,
         max_new_tokens=args.max_new_tokens, scheduler=args.scheduler,
-        use_reduced=args.reduced, greedy=not args.sample,
+        use_reduced=not args.full,
+        reduce_kw={"layers": args.layers} if args.layers else None,
+        layers=args.layers, attention_backend=args.attention_backend,
+        greedy=not args.sample,
         eos_id=args.eos_id if args.eos_id >= 0 else None, seed=args.seed,
         page_size=args.page_size, num_pages=args.num_pages or None,
         prefill_chunk_tokens=args.prefill_chunk,

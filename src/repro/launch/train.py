@@ -1,12 +1,14 @@
 """Training launcher: `python -m repro.launch.train --arch <id> [...]`.
 
-On this CPU container it runs reduced configs end-to-end (the e2e example
-trains a ~100M model for a few hundred steps); on a TPU fleet the same
-driver runs the full configs (the mesh adapts to jax.device_count()).
+By default it trains a reduced config (the e2e example trains a ~100M
+model for a few hundred steps on a CPU). ``--full`` keeps every published
+width and ``--layers`` then cuts only the depth, which is how one chip
+holds a slice of a large model. The mesh adapts to the device count.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import logging
 
 import jax
@@ -14,6 +16,7 @@ from jax.sharding import NamedSharding
 
 from repro.configs import RunConfig, ShapeConfig, get_arch, reduced
 from repro.data.pipeline import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh, set_mesh
 from repro.parallel import sharding as shd
 from repro.runtime import train_loop
@@ -21,16 +24,25 @@ from repro.runtime.steps import build_train_step
 from repro.runtime.elastic import choose_mesh
 
 
-def main(argv=None):
+def main(argv=None, *, devices=None):
+    """Run the training CLI; ``devices`` (default: all) are the devices
+    the mesh is built over."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=256)
-    ap.add_argument("--reduced", action="store_true", default=True)
-    ap.add_argument("--full", dest="reduced", action="store_false")
-    ap.add_argument("--layers", type=int, default=4)
-    ap.add_argument("--d-model", type=int, default=256)
+    ap.add_argument("--full", action="store_true",
+                    help="keep every published width; --layers then sets "
+                         "only the depth")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="layer count (default: 4 reduced, the published "
+                         "count with --full)")
+    ap.add_argument("--d-model", type=int, default=256,
+                    help="reduced model width (ignored with --full)")
+    ap.add_argument("--attention-backend",
+                    choices=("dense", "chunked", "pallas"), default=None,
+                    help="default: dense up to 512 tokens, chunked beyond")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--microbatches", type=int, default=1)
@@ -38,18 +50,24 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
-    if args.reduced:
-        cfg = reduced(cfg, layers=args.layers, d_model=args.d_model)
-    mesh_cfg = choose_mesh(jax.device_count())
+    if not args.full:
+        cfg = reduced(cfg, layers=args.layers or 4, d_model=args.d_model)
+    elif args.layers:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
+    backend = args.attention_backend or (
+        "dense" if args.seq <= 512 else "chunked")
+    devices = jax.devices() if devices is None else list(devices)
+    mesh_cfg = choose_mesh(len(devices))
     shape = ShapeConfig("custom", "train", args.seq, args.batch)
     rcfg = RunConfig(model=cfg, shape=shape, mesh=mesh_cfg,
                      microbatches=args.microbatches,
-                     attention_backend="dense" if args.seq <= 512 else "chunked",
+                     attention_backend=backend,
                      learning_rate=args.lr, param_dtype="float32",
                      warmup_steps=max(10, args.steps // 10))
-    mesh = make_mesh(mesh_cfg)
+    mesh = make_mesh(mesh_cfg, devices)
     data = SyntheticLM(cfg, args.batch, args.seq)
 
     with set_mesh(mesh):

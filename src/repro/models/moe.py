@@ -15,9 +15,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 
 from repro.configs.base import ModelConfig, MoEConfig
-from repro.parallel.compat import axis_size, shard_map
 
 
 def moe_init(key, cfg: ModelConfig, dtype) -> dict:

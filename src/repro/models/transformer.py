@@ -10,10 +10,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.configs.base import ModelConfig
-from repro.parallel.compat import shard_map
 from repro.models import attention as attn_mod
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
@@ -99,6 +99,33 @@ def _rope_q_k(cfg: ModelConfig, q, k, positions):
     return q, k
 
 
+def _attend(q, k, v, rt: Runtime, *, causal: bool, window: int = 0):
+    """``attn_mod.attention`` under ``rt``'s backend. XLA cannot partition
+    a Pallas kernel, so over a mesh of several devices the kernel runs
+    per shard: batch over ``rt.mesh_batch_axes``, heads over ``model``
+    when both head counts divide it (GQA groups stay whole, since head
+    shards are contiguous)."""
+
+    def run(q, k, v):
+        return attn_mod.attention(q, k, v, backend=rt.attention_backend,
+                                  causal=causal, window=window,
+                                  chunk=rt.chunk, block_q=rt.attn_block_q,
+                                  block_k=rt.attn_block_k)
+
+    from repro.parallel.sharding import have_ambient_mesh
+    if rt.attention_backend != "pallas" or not have_ambient_mesh():
+        return run(q, k, v)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.size == 1:
+        return run(q, k, v)
+    n_model = mesh.shape.get("model", 1)
+    heads = ("model" if q.shape[2] % n_model == 0
+             and k.shape[2] % n_model == 0 else None)
+    spec = PartitionSpec(rt.mesh_batch_axes or None, None, heads, None)
+    return shard_map(run, in_specs=(spec, spec, spec), out_specs=spec,
+                     check_vma=False)(q, k, v)
+
+
 # ===================================================================== init
 def layer_init(key, cfg: ModelConfig, dtype, *, cross: bool = False,
                bidirectional: bool = False) -> dict:
@@ -172,9 +199,7 @@ def layer_apply(p, x, cfg: ModelConfig, rt: Runtime, positions,
     q, k, v = attn_mod.project_qkv(p["attn"], h_in, h_in, cfg)
     q, k = _rope_q_k(cfg, q, k, positions)
     window = cfg.window if cfg.attention_kind == "sliding" else 0
-    o = attn_mod.attention(q, k, v, backend=rt.attention_backend,
-                           causal=causal, window=window, chunk=rt.chunk,
-                           block_q=rt.attn_block_q, block_k=rt.attn_block_k)
+    o = _attend(q, k, v, rt, causal=causal, window=window)
     h = o.reshape(*x.shape[:-1], -1) @ p["attn"]["wo"]
     cache_entry = {}
     if return_cache:
@@ -203,10 +228,7 @@ def layer_apply(p, x, cfg: ModelConfig, rt: Runtime, positions,
     if enc_out is not None:
         h_in = _norm(p["norm_cross"], x, cfg, rt)
         q, ck, cv = attn_mod.project_qkv(p["cross_attn"], h_in, enc_out, cfg)
-        o = attn_mod.attention(q, ck, cv, backend=rt.attention_backend,
-                               causal=False, chunk=rt.chunk,
-                               block_q=rt.attn_block_q,
-                               block_k=rt.attn_block_k)
+        o = _attend(q, ck, cv, rt, causal=False)
         x = _constrain(
             x + o.reshape(*x.shape[:-1], -1) @ p["cross_attn"]["wo"], rt)
         if return_cache:

@@ -19,14 +19,13 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.parallel.compat import shard_map
 
 
 def _shard_map(fn, in_specs, out_specs):
     return shard_map(fn, in_specs=in_specs, out_specs=out_specs,
-                         check_vma=False)
+                     check_vma=False)
 
 
 def partitioned_decode_attention(q, k_cache, v_cache, cache_len,

@@ -19,9 +19,9 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import PartitionSpec as P
-
-from repro.parallel.compat import axis_size, shard_map
 
 
 def stage_layout(num_layers: int, stage_layers: Sequence[int]):

@@ -61,6 +61,10 @@ class TrainLoopResult:
     retries: int
     stragglers: int
     checkpoints: list
+    # host seconds per completed step, from dispatch to the loss on the
+    # host; the first includes compilation
+    step_times: list = field(default_factory=list)
+    params: object = None            # the trained parameters
 
 
 def run(train_step: Callable, params, opt_state, data_iter_fn: Callable,
@@ -84,6 +88,7 @@ def run(train_step: Callable, params, opt_state, data_iter_fn: Callable,
             log.info("resumed from checkpoint step %d", step0)
 
     losses: list = []
+    step_times: list = []
     saves: list = []
     pending_save = None
     retries = 0
@@ -110,7 +115,8 @@ def run(train_step: Callable, params, opt_state, data_iter_fn: Callable,
             continue
         params, opt_state = new_params, new_opt
         losses.append(loss)
-        watchdog.observe(step, time.perf_counter() - t0)
+        step_times.append(time.perf_counter() - t0)
+        watchdog.observe(step, step_times[-1])
         if ckpt_dir and (step + 1) % ckpt_every == 0:
             if pending_save is not None:
                 pending_save.join()
@@ -127,4 +133,5 @@ def run(train_step: Callable, params, opt_state, data_iter_fn: Callable,
     return TrainLoopResult(final_step=step, losses=losses,
                            resumed_from=resumed_from, retries=retries,
                            stragglers=len(watchdog.flagged),
-                           checkpoints=saves)
+                           checkpoints=saves, step_times=step_times,
+                           params=params)

@@ -121,8 +121,6 @@ class _EngineBase:
         self.clock = clock or WallClock()
         self.prompt_to_batch = prompt_to_batch
         self._decode_fn = decode_fn
-        # buffer donation is a no-op on CPU and only triggers warnings
-        self._donate_ok = jax.default_backend() != "cpu"
         self._setup_jits(prefill_fn, decode_fn)
 
     def _setup_jits(self, prefill_fn, decode_fn) -> None:
@@ -139,7 +137,7 @@ class _EngineBase:
         # first-token sampling is fused in so admission is one dispatch
         self._jit_prefill = jax.jit(prefill_sample, static_argnums=(2,))
         self._jit_decode = jax.jit(
-            decode_fn, donate_argnums=(1,) if self._donate_ok else ())
+            decode_fn, donate_argnums=(1,))
 
     # ---- helpers shared by all schedulers
     def admission_error(self, r: Request) -> Optional[str]:
@@ -343,7 +341,7 @@ class ContinuousEngine(_EngineBase):
             }
 
         return jax.jit(pool_step,
-                       donate_argnums=(1, 2) if self._donate_ok else ())
+                       donate_argnums=(1, 2))
 
     def _admit_fn(self):
         """One fused dispatch per admission: insert the prefilled caches
@@ -365,7 +363,7 @@ class ContinuousEngine(_EngineBase):
             }
 
         return jax.jit(admit,
-                       donate_argnums=(0, 1) if self._donate_ok else ())
+                       donate_argnums=(0, 1))
 
     def run(self, requests: Sequence[Request]) -> ServeReport:
         sched = Scheduler(self)

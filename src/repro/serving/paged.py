@@ -125,17 +125,16 @@ class PagedEngine(_EngineBase):
 
     # --------------------------------------------------------------- jits
     def _setup_jits(self, prefill_fn, decode_fn) -> None:
-        donate = self._donate_ok
         # one compile per chunk length; start_pos stays traced
         self._jit_chunk = jax.jit(
-            prefill_fn, donate_argnums=(1,) if donate else ())
+            prefill_fn, donate_argnums=(1,))
         # copy-on-write: duplicate page src into page dst across every
         # pool leaf (axis 0 = layers, axis 1 = pages); src/dst stay
         # traced so one compile covers every divergence point
         self._jit_copy = jax.jit(
             lambda caches, src, dst: jax.tree.map(
                 lambda a: a.at[:, dst].set(a[:, src]), caches),
-            donate_argnums=(0,) if donate else ())
+            donate_argnums=(0,))
         greedy, eos_id = self.greedy, self.eos_id
 
         def pool_step(params, caches, state, key):
@@ -193,11 +192,11 @@ class PagedEngine(_EngineBase):
             }
 
         self._pool_step = jax.jit(
-            pool_step, donate_argnums=(1, 2) if donate else ())
+            pool_step, donate_argnums=(1, 2))
         self._admit = jax.jit(
-            admit, donate_argnums=(0,) if donate else ())
+            admit, donate_argnums=(0,))
         self._jit_evict = jax.jit(
-            evict, donate_argnums=(0,) if donate else ())
+            evict, donate_argnums=(0,))
 
     def warmup(self, prompt_len: int) -> None:
         # jit-compile warmup must not consume the fault schedule (every

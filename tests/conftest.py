@@ -8,8 +8,6 @@ import pytest
 REPO = Path(__file__).resolve().parents[1]
 SRC = REPO / "src"
 sys.path.insert(0, str(SRC))
-# make the _hypothesis_compat shim importable regardless of import mode
-sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 
 def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600):

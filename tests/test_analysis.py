@@ -305,7 +305,7 @@ def test_f64_leak_flagged():
     def leak(x):
         return x.astype(jnp.float64).sum()
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         findings = graph_audit.audit_function("leak", leak, jnp.ones(4))
     assert any(f.rule == "RG002" for f in findings)
 

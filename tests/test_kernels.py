@@ -1,8 +1,7 @@
 """Pallas kernel validation: interpret-mode execution vs pure-jnp oracles,
-with hypothesis sweeps over shapes/dtypes (deterministic fallback sampler
-when hypothesis isn't installed — see tests/_hypothesis_compat.py)."""
+with hypothesis sweeps over shapes/dtypes."""
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import jax
 import jax.numpy as jnp

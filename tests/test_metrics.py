@@ -2,7 +2,7 @@
 HLO-analyzer verification against hand-built modules, section partitioner
 invariants."""
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import ARCHS, SHAPES, MeshConfig
 from repro.core import metrics, sections

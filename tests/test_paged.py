@@ -4,7 +4,7 @@ paged decode-attention kernel vs the gather reference, tuned tile-param
 wiring, chunked-prefill equivalence, paged-vs-monolithic greedy token
 parity across mixed prompt lengths, and the symmetric admission
 validation shared by all three schedulers."""
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import numpy as np
 import pytest
@@ -348,6 +348,23 @@ def test_paged_engine_token_streams_and_page_reuse():
     assert rep.page_occupancy_peak <= 1.0
     s = rep.summary()
     assert s["num_pages"] == 6 and s["page_size"] == 4
+
+
+def test_paged_engine_donates_pool_and_state():
+    """The fused pool step consumes its cache and lane-state buffers on
+    every backend, the CPU included, so a stale read fails in tests as
+    it would on the chip."""
+    from repro.serving.roles import DecodeWorker
+
+    eng = _paged_stub_engine(slots=2, cache_span=16, page_size=4,
+                             num_pages=6)
+    caches = eng.cache_init(eng.num_pages, eng.page_size)
+    state = DecodeWorker(eng, 2, npag_max=eng.npag_max).state
+    new_caches, new_state = eng._pool_step(None, caches, state,
+                                           jax.random.PRNGKey(0))
+    assert caches["k"].is_deleted() and state["tokbuf"].is_deleted()
+    assert not new_caches["k"].is_deleted()
+    assert not new_state["tokbuf"].is_deleted()
 
 
 # --------------------------------------------------- symmetric validation

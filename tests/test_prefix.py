@@ -9,7 +9,7 @@ import subprocess
 import sys
 from pathlib import Path
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import numpy as np
 import pytest

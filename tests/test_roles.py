@@ -3,7 +3,7 @@ disaggregated engine: PageHandoff ownership invariants (never dual-held,
 refcount-conserving, PoolInvariantError on protocol violations), the
 extracted Scheduler's reaping/preemption/deadline-truncation policy, and
 disaggregated-vs-interleaved greedy token parity on stub engines."""
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import numpy as np
 import pytest

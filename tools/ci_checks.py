@@ -45,8 +45,8 @@ doctored prediction must make the gate trip (self-test).
 ``doc-refs`` is the documentation lint: ``FILE.md §N``-style references
 must resolve to an existing file with that section heading, and CLI
 flags named in README/EXPERIMENTS/DESIGN prose must be defined by some
-``launch/*``/``benchmarks/run``/``tools`` argparse; a planted dangling
-reference must fire (self-test).
+``launch/*``/``benchmarks/run``/``tools``/``chip_smoke.py`` argparse;
+a planted dangling reference must fire (self-test).
 
 Every check takes ``--jsonl`` (default ``results/bench/latest.jsonl``)
 and exits 0/1; assertion messages name the offending record.
@@ -760,6 +760,7 @@ def _doc_ref_findings(root: Path) -> list:
         *sorted((root / "src" / "repro" / "analysis").glob("__main__.py")),
         root / "benchmarks" / "run.py",
         root / "tools" / "ci_checks.py",
+        root / "chip_smoke.py",
     ]
     arg_re = re.compile(r"add_argument\(\s*[\"'](--[A-Za-z0-9][A-Za-z0-9-]*)")
     for src in cli_sources:
@@ -802,8 +803,8 @@ def _doc_ref_findings(root: Path) -> list:
                     continue
                 findings.append(
                     f"{rel}: CLI flag '{flag}' is not defined by any "
-                    "launch/*, benchmarks/run, repro.analysis, or "
-                    "ci_checks argparse"
+                    "launch/*, benchmarks/run, repro.analysis, ci_checks "
+                    "or chip_smoke argparse"
                 )
     return findings
 
@@ -816,7 +817,8 @@ def check_doc_refs(args: argparse.Namespace) -> int:
       EXPERIMENTS.md cites §2/§4 by number, so the numbers are API);
     * every ``--flag`` named in README/EXPERIMENTS/DESIGN/findings prose
       must be defined by an ``add_argument`` in ``launch/*``,
-      ``benchmarks/run``, ``repro.analysis``, or ``tools/ci_checks``;
+      ``benchmarks/run``, ``repro.analysis``, ``tools/ci_checks`` or
+      ``chip_smoke.py``;
     * self-test: a planted fixture tree with a dangling §-reference and
       an undefined flag MUST produce findings — proving the lint fires.
     """
