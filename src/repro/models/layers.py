@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.runtime import trace_names as N
 
 # M-RoPE head-dim half split into (temporal, height, width) sections,
 # per Qwen2-VL (arXiv:2409.12191).
@@ -32,16 +33,17 @@ def norm_init(d: int, kind: str):
 
 def apply_norm(p, x, kind: str, eps: float = 1e-5):
     """Norm in f32, output in input dtype."""
-    xf = x.astype(jnp.float32)
-    if kind == "layernorm":
-        mean = xf.mean(-1, keepdims=True)
-        var = ((xf - mean) ** 2).mean(-1, keepdims=True)
-        y = (xf - mean) * jax.lax.rsqrt(var + eps)
-        y = y * p["scale"] + p["bias"]
-    else:  # rmsnorm
-        ms = (xf * xf).mean(-1, keepdims=True)
-        y = xf * jax.lax.rsqrt(ms + eps) * p["scale"]
-    return y.astype(x.dtype)
+    with jax.named_scope(N.NORM):
+        xf = x.astype(jnp.float32)
+        if kind == "layernorm":
+            mean = xf.mean(-1, keepdims=True)
+            var = ((xf - mean) ** 2).mean(-1, keepdims=True)
+            y = (xf - mean) * jax.lax.rsqrt(var + eps)
+            y = y * p["scale"] + p["bias"]
+        else:  # rmsnorm
+            ms = (xf * xf).mean(-1, keepdims=True)
+            y = xf * jax.lax.rsqrt(ms + eps) * p["scale"]
+        return y.astype(x.dtype)
 
 
 # ---------------------------------------------------------------- rotary

@@ -26,6 +26,7 @@ from repro.models.layers import (
     sinusoidal_positions,
 )
 from repro.models.transformer import Runtime
+from repro.runtime import trace_names as N
 
 
 @dataclass(frozen=True)
@@ -98,17 +99,21 @@ def build(cfg: ModelConfig, rt: Runtime, param_dtype=jnp.bfloat16) -> Model:
     # ----------------------------------------------------------- loss
     def loss(params, batch):
         enc_out = _encode(params, batch) if cfg.is_enc_dec else None
-        x, positions = _embed_inputs(params, batch)
+        with jax.named_scope(N.EMBED):
+            x, positions = _embed_inputs(params, batch)
         x, aux = tfm.stack_apply(params["layers"], x, cfg, rt, positions,
                                  enc_out=enc_out, causal=True)
         x = apply_norm(params["final_norm"], x, cfg.norm)
-        logits = lm_logits(params["embed"], x, cfg.tie_embeddings,
-                           true_vocab=cfg.vocab_size)
-        logits = logits.astype(jnp.float32)
-        labels = batch["labels"]
-        logz = jax.scipy.special.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-        nll = (logz - gold).mean()
+        with jax.named_scope(N.LM_HEAD):
+            logits = lm_logits(params["embed"], x, cfg.tie_embeddings,
+                               true_vocab=cfg.vocab_size)
+            logits = logits.astype(jnp.float32)
+        with jax.named_scope(N.LOSS):
+            labels = batch["labels"]
+            logz = jax.scipy.special.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, labels[..., None],
+                                       axis=-1)[..., 0]
+            nll = (logz - gold).mean()
         total = nll + aux.get("aux_loss", 0.0)
         aux_out = {"nll": nll, **{k: v for k, v in aux.items()}}
         return total, aux_out
@@ -116,7 +121,8 @@ def build(cfg: ModelConfig, rt: Runtime, param_dtype=jnp.bfloat16) -> Model:
     # ----------------------------------------------------------- prefill
     def prefill(params, batch, cache_span: int):
         enc_out = _encode(params, batch) if cfg.is_enc_dec else None
-        x, positions = _embed_inputs(params, batch)
+        with jax.named_scope(N.EMBED):
+            x, positions = _embed_inputs(params, batch)
         x, layer_caches = tfm.stack_prefill(params["layers"], x, cfg, rt,
                                             positions, enc_out=enc_out,
                                             cache_span=cache_span)
@@ -124,11 +130,8 @@ def build(cfg: ModelConfig, rt: Runtime, param_dtype=jnp.bfloat16) -> Model:
         if cfg.is_enc_dec:  # split cross-attention cache out of layer caches
             caches["cross"] = {"ck": layer_caches.pop("ck"),
                                "cv": layer_caches.pop("cv")}
-        x_last = x[:, -1:]
-        x_last = apply_norm(params["final_norm"], x_last, cfg.norm)
-        logits = lm_logits(params["embed"], x_last, cfg.tie_embeddings,
-                           true_vocab=cfg.vocab_size)
-        return logits.astype(jnp.float32)[..., :cfg.vocab_size], caches
+        x_last = apply_norm(params["final_norm"], x[:, -1:], cfg.norm)
+        return _logits(params, x_last), caches
 
     # ----------------------------------------------------------- decode
     def _sinusoidal_at(pos):
@@ -141,22 +144,32 @@ def build(cfg: ModelConfig, rt: Runtime, param_dtype=jnp.bfloat16) -> Model:
         pe = jnp.zeros((pos_v.shape[0], d), jnp.float32)
         return pe.at[:, 0::2].set(jnp.sin(ang)).at[:, 1::2].set(jnp.cos(ang))
 
+    def _logits(params, x):
+        """Float32 logits of the true vocabulary at ``x``."""
+        with jax.named_scope(N.LM_HEAD):
+            logits = lm_logits(params["embed"], x, cfg.tie_embeddings,
+                               true_vocab=cfg.vocab_size)
+            return logits.astype(jnp.float32)[..., :cfg.vocab_size]
+
+    def _embed_decode(params, token, pos):
+        with jax.named_scope(N.EMBED):
+            x = embed_tokens(params["embed"], token).astype(compute_dtype)
+            if cfg.rope == "sinusoidal":
+                x = x + _sinusoidal_at(pos)[:, None].astype(x.dtype)
+            return x
+
     def decode_step(params, caches, token, pos):
         """token: (B,1) i32; pos: scalar i32 (next position to write) or a
         (B,) vector of per-row positions (continuous batching)."""
-        x = embed_tokens(params["embed"], token).astype(compute_dtype)
-        if cfg.rope == "sinusoidal":
-            x = x + _sinusoidal_at(pos)[:, None].astype(x.dtype)
+        x = _embed_decode(params, token, pos)
         cross = caches.get("cross")
         x, new_layer_caches = tfm.stack_decode(
             params["layers"], x, caches["layers"], pos, cfg, rt,
             cross_caches=cross)
         x = apply_norm(params["final_norm"], x, cfg.norm)
-        logits = lm_logits(params["embed"], x, cfg.tie_embeddings,
-                           true_vocab=cfg.vocab_size)
         new_caches = dict(caches)
         new_caches["layers"] = new_layer_caches
-        return logits.astype(jnp.float32)[..., :cfg.vocab_size], new_caches
+        return _logits(params, x), new_caches
 
     # ------------------------------------------------------ paged serving
     def prefill_chunk(params, caches, tokens, block_tables, start_pos):
@@ -167,34 +180,27 @@ def build(cfg: ModelConfig, rt: Runtime, param_dtype=jnp.bfloat16) -> Model:
         pools — feeding the prompt chunk-by-chunk fills pages
         incrementally and the final chunk's logits seed decoding, exactly
         like one-shot ``prefill``."""
-        x = embed_tokens(params["embed"], tokens).astype(compute_dtype)
         C = tokens.shape[1]
         positions = start_pos + jnp.arange(C)
-        if cfg.rope == "sinusoidal":
-            x = x + _sinusoidal_at(positions)[None].astype(x.dtype)
+        with jax.named_scope(N.EMBED):
+            x = embed_tokens(params["embed"], tokens).astype(compute_dtype)
+            if cfg.rope == "sinusoidal":
+                x = x + _sinusoidal_at(positions)[None].astype(x.dtype)
         x, new_layer = tfm.stack_prefill_chunk(
             params["layers"], x, caches["layers"], block_tables, positions,
             cfg, rt)
         x_last = apply_norm(params["final_norm"], x[:, -1:], cfg.norm)
-        logits = lm_logits(params["embed"], x_last, cfg.tie_embeddings,
-                           true_vocab=cfg.vocab_size)
-        return logits.astype(jnp.float32)[..., :cfg.vocab_size], \
-            {"layers": new_layer}
+        return _logits(params, x_last), {"layers": new_layer}
 
     def decode_step_paged(params, caches, token, pos, block_tables):
         """token: (B,1) i32; pos: (B,) next position per row;
         block_tables: (B, n_pages) physical page ids."""
-        x = embed_tokens(params["embed"], token).astype(compute_dtype)
-        if cfg.rope == "sinusoidal":
-            x = x + _sinusoidal_at(pos)[:, None].astype(x.dtype)
+        x = _embed_decode(params, token, pos)
         x, new_layer = tfm.stack_decode_paged(
             params["layers"], x, caches["layers"], pos, block_tables, cfg,
             rt)
         x = apply_norm(params["final_norm"], x, cfg.norm)
-        logits = lm_logits(params["embed"], x, cfg.tie_embeddings,
-                           true_vocab=cfg.vocab_size)
-        return logits.astype(jnp.float32)[..., :cfg.vocab_size], \
-            {"layers": new_layer}
+        return _logits(params, x), {"layers": new_layer}
 
     def paged_cache_init(num_pages: int, page_size: int,
                          dtype=param_dtype):

@@ -25,6 +25,7 @@ from repro.models.layers import (
     mlp_init,
     norm_init,
 )
+from repro.runtime.trace_names import ATTENTION, KV_WRITE, LAYERS, MLP, MOE
 
 
 @dataclass(frozen=True)
@@ -126,6 +127,15 @@ def _attend(q, k, v, rt: Runtime, *, causal: bool, window: int = 0):
                      check_vma=False)(q, k, v)
 
 
+def _ffn(p, h_in, cfg: ModelConfig, rt: Runtime):
+    """The layer's MLP or MoE, each under its scope. Returns (h, aux)."""
+    if cfg.moe is not None:
+        with jax.named_scope(MOE):
+            return moe_mod.moe_ffn(p["moe"], h_in, cfg, rt)
+    with jax.named_scope(MLP):
+        return apply_mlp(p["mlp"], h_in, cfg.activation), {}
+
+
 # ===================================================================== init
 def layer_init(key, cfg: ModelConfig, dtype, *, cross: bool = False,
                bidirectional: bool = False) -> dict:
@@ -196,11 +206,12 @@ def layer_apply(p, x, cfg: ModelConfig, rt: Runtime, positions,
 
     # ---- mixer: attention (+ parallel ssd heads for hybrid) ----
     h_in = _norm(p["norm1"], x, cfg, rt)
-    q, k, v = attn_mod.project_qkv(p["attn"], h_in, h_in, cfg)
-    q, k = _rope_q_k(cfg, q, k, positions)
     window = cfg.window if cfg.attention_kind == "sliding" else 0
-    o = _attend(q, k, v, rt, causal=causal, window=window)
-    h = o.reshape(*x.shape[:-1], -1) @ p["attn"]["wo"]
+    with jax.named_scope(ATTENTION):
+        q, k, v = attn_mod.project_qkv(p["attn"], h_in, h_in, cfg)
+        q, k = _rope_q_k(cfg, q, k, positions)
+        o = _attend(q, k, v, rt, causal=causal, window=window)
+        h = o.reshape(*x.shape[:-1], -1) @ p["attn"]["wo"]
     cache_entry = {}
     if return_cache:
         if cfg.attention_kind == "sliding":
@@ -227,20 +238,18 @@ def layer_apply(p, x, cfg: ModelConfig, rt: Runtime, positions,
     # ---- cross attention (whisper decoder) ----
     if enc_out is not None:
         h_in = _norm(p["norm_cross"], x, cfg, rt)
-        q, ck, cv = attn_mod.project_qkv(p["cross_attn"], h_in, enc_out, cfg)
-        o = _attend(q, ck, cv, rt, causal=False)
-        x = _constrain(
-            x + o.reshape(*x.shape[:-1], -1) @ p["cross_attn"]["wo"], rt)
+        with jax.named_scope(ATTENTION):
+            q, ck, cv = attn_mod.project_qkv(p["cross_attn"], h_in, enc_out,
+                                             cfg)
+            o = _attend(q, ck, cv, rt, causal=False)
+            h = o.reshape(*x.shape[:-1], -1) @ p["cross_attn"]["wo"]
+        x = _constrain(x + h, rt)
         if return_cache:
             cache_entry["ck"], cache_entry["cv"] = ck, cv
 
     # ---- mlp / moe ----
-    h_in = _norm(p["norm2"], x, cfg, rt)
-    if cfg.moe is not None:
-        h, moe_aux = moe_mod.moe_ffn(p["moe"], h_in, cfg, rt)
-        aux.update(moe_aux)
-    else:
-        h = apply_mlp(p["mlp"], h_in, cfg.activation)
+    h, ffn_aux = _ffn(p, _norm(p["norm2"], x, cfg, rt), cfg, rt)
+    aux.update(ffn_aux)
     if rt.pin_mixer_output:
         h = _constrain(h, rt)   # force the TP psum in bf16 (§Perf)
     x = _constrain(x + h, rt)
@@ -282,7 +291,8 @@ def stack_apply(stacked, x, cfg: ModelConfig, rt: Runtime, positions,
         body, xs = one_layer, stacked
 
     fn = jax.checkpoint(body) if rt.remat else body
-    x, aux_stack = jax.lax.scan(fn, x, xs)
+    with jax.named_scope(LAYERS):
+        x, aux_stack = jax.lax.scan(fn, x, xs)
     aux = {"aux_loss": aux_stack["aux_loss"].sum()}
     if "expert_load" in aux_stack:
         el = aux_stack["expert_load"]
@@ -300,8 +310,8 @@ def stack_prefill(stacked, x, cfg: ModelConfig, rt: Runtime, positions,
                                   cache_span=cache_span)
         return y, cache
 
-    x, caches = jax.lax.scan(body, x, stacked)
-    return x, caches
+    with jax.named_scope(LAYERS):
+        return jax.lax.scan(body, x, stacked)
 
 
 # ================================================================= caches
@@ -401,29 +411,35 @@ def layer_decode(p, x, cache, pos, cfg: ModelConfig, rt: Runtime,
         return x, new_cache
 
     h_in = apply_norm(p["norm1"], x, cfg.norm)
-    q, k, v = attn_mod.project_qkv(p["attn"], h_in, h_in, cfg)
-    pos_b = jnp.broadcast_to(pos.reshape(-1, 1), (x.shape[0], 1))
-    q, k = _rope_q_k(cfg, q, k, pos_b if cfg.rope != "mrope" else
-                     jnp.broadcast_to(pos_b[:, None], (x.shape[0], 3, 1)))
+    with jax.named_scope(ATTENTION):
+        q, k, v = attn_mod.project_qkv(p["attn"], h_in, h_in, cfg)
+        pos_b = jnp.broadcast_to(pos.reshape(-1, 1), (x.shape[0], 1))
+        q, k = _rope_q_k(cfg, q, k, pos_b if cfg.rope != "mrope" else
+                         jnp.broadcast_to(pos_b[:, None],
+                                          (x.shape[0], 3, 1)))
     span = cache["k"].shape[1]
     slot = pos % span if cfg.attention_kind == "sliding" else pos
-    if per_row:
-        bidx = jnp.arange(x.shape[0])
-        k_cache = cache["k"].at[bidx, slot].set(k[:, 0])
-        v_cache = cache["v"].at[bidx, slot].set(v[:, 0])
-    else:
-        k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, slot,
-                                                      axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, slot,
-                                                      axis=1)
+    with jax.named_scope(KV_WRITE):
+        if per_row:
+            bidx = jnp.arange(x.shape[0])
+            k_cache = cache["k"].at[bidx, slot].set(k[:, 0])
+            v_cache = cache["v"].at[bidx, slot].set(v[:, 0])
+        else:
+            k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k,
+                                                          slot, axis=1)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], v,
+                                                          slot, axis=1)
     cache_len = jnp.minimum(pos + 1, span)
-    if rt.decode_partitioned and cfg.attention_kind == "full":
-        from repro.parallel.collectives import partitioned_decode_attention
-        o = partitioned_decode_attention(q, k_cache, v_cache, cache_len,
-                                         batch_axes=rt.mesh_batch_axes)
-    else:
-        o = attn_mod.decode_attention_simple(q, k_cache, v_cache, cache_len)
-    h = o.reshape(*x.shape[:-1], -1) @ p["attn"]["wo"]
+    with jax.named_scope(ATTENTION):
+        if rt.decode_partitioned and cfg.attention_kind == "full":
+            from repro.parallel.collectives import \
+                partitioned_decode_attention
+            o = partitioned_decode_attention(q, k_cache, v_cache, cache_len,
+                                             batch_axes=rt.mesh_batch_axes)
+        else:
+            o = attn_mod.decode_attention_simple(q, k_cache, v_cache,
+                                                 cache_len)
+        h = o.reshape(*x.shape[:-1], -1) @ p["attn"]["wo"]
     new_cache["k"], new_cache["v"] = k_cache, v_cache
     if cfg.family == "hybrid":
         # one-step ssd
@@ -469,11 +485,7 @@ def layer_decode(p, x, cache, pos, cfg: ModelConfig, rt: Runtime,
                 q, cross_cache["ck"], cross_cache["cv"], enc_len)
         x = x + o.reshape(*x.shape[:-1], -1) @ p["cross_attn"]["wo"]
 
-    h_in = apply_norm(p["norm2"], x, cfg.norm)
-    if cfg.moe is not None:
-        h, _ = moe_mod.moe_ffn(p["moe"], h_in, cfg, rt)
-    else:
-        h = apply_mlp(p["mlp"], h_in, cfg.activation)
+    h, _ = _ffn(p, apply_norm(p["norm2"], x, cfg.norm), cfg, rt)
     return x + h, new_cache
 
 
@@ -500,23 +512,24 @@ def layer_decode_paged(p, x, cache, pos, block_tables, cfg: ModelConfig,
     pages, so the scatter never races."""
     pos = jnp.asarray(pos)
     h_in = apply_norm(p["norm1"], x, cfg.norm)
-    q, k, v = attn_mod.project_qkv(p["attn"], h_in, h_in, cfg)
-    pos_b = jnp.broadcast_to(pos.reshape(-1, 1), (x.shape[0], 1))
-    q, k = _rope_q_k(cfg, q, k, pos_b if cfg.rope != "mrope" else
-                     jnp.broadcast_to(pos_b[:, None], (x.shape[0], 3, 1)))
-    ps = cache["k"].shape[1]
-    bidx = jnp.arange(x.shape[0])
-    pages = block_tables[bidx, pos // ps]
-    offs = pos % ps
-    k_pool = cache["k"].at[pages, offs].set(k[:, 0])
-    v_pool = cache["v"].at[pages, offs].set(v[:, 0])
-    o = _paged_attend(q, k_pool, v_pool, block_tables, pos + 1, rt)
-    x = x + o.reshape(*x.shape[:-1], -1) @ p["attn"]["wo"]
-    h_in = apply_norm(p["norm2"], x, cfg.norm)
-    if cfg.moe is not None:
-        h, _ = moe_mod.moe_ffn(p["moe"], h_in, cfg, rt)
-    else:
-        h = apply_mlp(p["mlp"], h_in, cfg.activation)
+    with jax.named_scope(ATTENTION):
+        q, k, v = attn_mod.project_qkv(p["attn"], h_in, h_in, cfg)
+        pos_b = jnp.broadcast_to(pos.reshape(-1, 1), (x.shape[0], 1))
+        q, k = _rope_q_k(cfg, q, k, pos_b if cfg.rope != "mrope" else
+                         jnp.broadcast_to(pos_b[:, None],
+                                          (x.shape[0], 3, 1)))
+    with jax.named_scope(KV_WRITE):
+        ps = cache["k"].shape[1]
+        bidx = jnp.arange(x.shape[0])
+        pages = block_tables[bidx, pos // ps]
+        offs = pos % ps
+        k_pool = cache["k"].at[pages, offs].set(k[:, 0])
+        v_pool = cache["v"].at[pages, offs].set(v[:, 0])
+    with jax.named_scope(ATTENTION):
+        o = _paged_attend(q, k_pool, v_pool, block_tables, pos + 1, rt)
+        h = o.reshape(*x.shape[:-1], -1) @ p["attn"]["wo"]
+    x = x + h
+    h, _ = _ffn(p, apply_norm(p["norm2"], x, cfg.norm), cfg, rt)
     return x + h, {"k": k_pool, "v": v_pool}
 
 
@@ -531,7 +544,8 @@ def stack_decode_paged(stacked, x, caches, pos, block_tables,
                                           block_tables, cfg, rt)
         return y, new_cache
 
-    return jax.lax.scan(body, x, (stacked, caches))
+    with jax.named_scope(LAYERS):
+        return jax.lax.scan(body, x, (stacked, caches))
 
 
 def layer_prefill_chunk(p, x, cache, block_tables, positions,
@@ -546,27 +560,27 @@ def layer_prefill_chunk(p, x, cache, block_tables, positions,
     ``q_offset`` — the same masked-softmax math as the one-shot prefill,
     summed in the same (logical-position) order."""
     h_in = apply_norm(p["norm1"], x, cfg.norm)
-    q, k, v = attn_mod.project_qkv(p["attn"], h_in, h_in, cfg)
-    q, k = _rope_q_k(cfg, q, k, positions[None] if cfg.rope != "mrope"
-                     else jnp.broadcast_to(positions[None, None],
-                                           (1, 3, positions.shape[0])))
+    with jax.named_scope(ATTENTION):
+        q, k, v = attn_mod.project_qkv(p["attn"], h_in, h_in, cfg)
+        q, k = _rope_q_k(cfg, q, k, positions[None] if cfg.rope != "mrope"
+                         else jnp.broadcast_to(positions[None, None],
+                                               (1, 3, positions.shape[0])))
     B, C = x.shape[0], x.shape[1]
     ps = cache["k"].shape[1]
     npag = block_tables.shape[1]
-    pages = jnp.take(block_tables, positions // ps, axis=1)     # (B, C)
-    offs = jnp.broadcast_to((positions % ps)[None], (B, C))
-    k_pool = cache["k"].at[pages, offs].set(k)
-    v_pool = cache["v"].at[pages, offs].set(v)
-    k_all = k_pool[block_tables].reshape(B, npag * ps, *k.shape[2:])
-    v_all = v_pool[block_tables].reshape(B, npag * ps, *v.shape[2:])
-    o = attn_mod.dense_attention(q, k_all, v_all, causal=True,
-                                 q_offset=positions[0])
-    x = x + o.reshape(*x.shape[:-1], -1) @ p["attn"]["wo"]
-    h_in = apply_norm(p["norm2"], x, cfg.norm)
-    if cfg.moe is not None:
-        h, _ = moe_mod.moe_ffn(p["moe"], h_in, cfg, rt)
-    else:
-        h = apply_mlp(p["mlp"], h_in, cfg.activation)
+    with jax.named_scope(KV_WRITE):
+        pages = jnp.take(block_tables, positions // ps, axis=1)  # (B, C)
+        offs = jnp.broadcast_to((positions % ps)[None], (B, C))
+        k_pool = cache["k"].at[pages, offs].set(k)
+        v_pool = cache["v"].at[pages, offs].set(v)
+    with jax.named_scope(ATTENTION):
+        k_all = k_pool[block_tables].reshape(B, npag * ps, *k.shape[2:])
+        v_all = v_pool[block_tables].reshape(B, npag * ps, *v.shape[2:])
+        o = attn_mod.dense_attention(q, k_all, v_all, causal=True,
+                                     q_offset=positions[0])
+        h = o.reshape(*x.shape[:-1], -1) @ p["attn"]["wo"]
+    x = x + h
+    h, _ = _ffn(p, apply_norm(p["norm2"], x, cfg.norm), cfg, rt)
     return x + h, {"k": k_pool, "v": v_pool}
 
 
@@ -582,7 +596,8 @@ def stack_prefill_chunk(stacked, x, caches, block_tables, positions,
                                            rt)
         return y, new_cache
 
-    return jax.lax.scan(body, x, (stacked, caches))
+    with jax.named_scope(LAYERS):
+        return jax.lax.scan(body, x, (stacked, caches))
 
 
 def stack_decode(stacked, x, caches, pos, cfg: ModelConfig, rt: Runtime,
@@ -601,5 +616,5 @@ def stack_decode(stacked, x, caches, pos, cfg: ModelConfig, rt: Runtime,
 
     xs = (stacked, caches, cross_caches) if cross_caches is not None \
         else (stacked, caches)
-    x, new_caches = jax.lax.scan(body, x, xs)
-    return x, new_caches
+    with jax.named_scope(LAYERS):
+        return jax.lax.scan(body, x, xs)

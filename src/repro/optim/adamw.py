@@ -19,6 +19,8 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.runtime import trace_names as N
+
 _QBLOCK = 16  # along the last dim: small enough to stay inside any shard
 
 
@@ -112,6 +114,10 @@ class AdamW:
                           m=zeros(), v=zeros())
 
     def update(self, grads, state: AdamWState, params):
+        with jax.named_scope(N.OPTIMIZER):
+            return self._update(grads, state, params)
+
+    def _update(self, grads, state: AdamWState, params):
         step = state.step + 1
         lr = self.lr_fn(step)
         gnorm = global_norm(grads)

@@ -20,8 +20,10 @@ from dataclasses import dataclass, field
 from typing import Callable, Optional
 
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.checkpoint import checkpoint as ckpt
+from repro.runtime import trace_names as N
 
 log = logging.getLogger("repro.train")
 
@@ -94,14 +96,17 @@ def run(train_step: Callable, params, opt_state, data_iter_fn: Callable,
     retries = 0
     step = start
     while step < total_steps:
-        batch = data_iter_fn(step)
+        with TraceAnnotation(N.TRAIN_BATCH, step=step):
+            batch = data_iter_fn(step)
         t0 = time.perf_counter()
         try:
-            if fail_injector is not None:
-                fail_injector(step)
-            new_params, new_opt, metrics = train_step(params, opt_state,
-                                                      batch)
-            loss = float(metrics["loss"])
+            with StepTraceAnnotation(N.TRAIN_STEP_GROUP, step_num=step), \
+                    TraceAnnotation(N.TRAIN_STEP, step=step):
+                if fail_injector is not None:
+                    fail_injector(step)
+                new_params, new_opt, metrics = train_step(params, opt_state,
+                                                          batch)
+                loss = float(metrics["loss"])
         except Exception as e:             # transient failure -> retry
             retries += 1
             log.warning("step %d failed (%s); retry %d/%d", step, e,

@@ -268,7 +268,8 @@ class DisaggregatedEngine(PagedEngine):
                     if inj is not None:
                         inj.check_prefill()
                     (logits, chunks), cost = metered(
-                        w.prefill, prompt_np, btab_dev, clock, start=s0)
+                        w.prefill, prompt_np, btab_dev, clock, rid=req.rid,
+                        start=s0)
                 except InjectedFault:
                     handoff.abort(req.rid)
                     audit()

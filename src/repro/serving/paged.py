@@ -51,13 +51,15 @@ triple, this engine takes the *paged* triple from
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, Optional, Sequence
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
+from repro.runtime import trace_names as N
 from repro.serving.engine import SCHEDULERS, _EngineBase, _sample_tokens
 from repro.serving.faults import FaultInjector, FaultPlan, InjectedFault
 from repro.serving.pages import (PageAllocator, PoolInvariantError, PoolStats,
@@ -140,6 +142,10 @@ class PagedEngine(_EngineBase):
         def pool_step(params, caches, state, key):
             logits, caches = decode_fn(params, caches, state["tok"],
                                        state["pos"], state["btab"])
+            with jax.named_scope(N.SAMPLE):
+                return caches, sample(logits, state, key)
+
+        def sample(logits, state, key):
             tok = _sample_tokens(logits[:, -1], key, greedy)      # (B,)
             active = state["active"]
             ncount = state["ncount"]
@@ -154,7 +160,7 @@ class PagedEngine(_EngineBase):
             if eos_id is not None:
                 stop = stop | (tok == eos_id)
             still = active & ~stop
-            return caches, {
+            return {
                 "tok": jnp.where(active, tok, state["tok"][:, 0])[:, None],
                 "pos": state["pos"] + active.astype(jnp.int32),
                 "active": still,
@@ -219,12 +225,12 @@ class PagedEngine(_EngineBase):
 
     # ---------------------------------------------------------- prefill
     def _chunked_prefill(self, prompt: np.ndarray, btab_dev, clock, *,
-                         start: int = 0):
-        """Stream prompt positions ``[start, len)`` through the pool in
-        page-filling chunks; returns the last chunk's logits and the
-        number of chunks run. ``start > 0`` is the warm path: positions
-        below it are already resident in attached prefix pages, so only
-        the suffix pays prefill compute.
+                         rid: int, start: int = 0):
+        """Stream request ``rid``'s prompt positions ``[start, len)``
+        through the pool in page-filling chunks; returns the last chunk's
+        logits and the number of chunks run. ``start > 0`` is the warm
+        path: positions below it are already resident in attached prefix
+        pages, so only the suffix pays prefill compute.
 
         Each chunk sees only the first ``pages_needed(written)`` pages of
         the block table, so attention cost grows with the live prefix
@@ -236,13 +242,15 @@ class PagedEngine(_EngineBase):
         chunks = 0
         for lo in range(start, plen, cs):
             end = min(lo + cs, plen)
-            n_live = pages_needed(end, self.page_size)
-            chunk = jnp.asarray(prompt[None, lo:end])
-            logits, self._caches = self._jit_chunk(
-                self.params, self._caches, chunk, btab_dev[:, :n_live],
-                jnp.int32(lo))
-            jax.block_until_ready(logits)
-            clock.charge("prefill")     # each chunk is a prefill dispatch
+            with TraceAnnotation(N.PREFILL_CHUNK, rid=rid, start=lo,
+                                 tokens=end - lo):
+                n_live = pages_needed(end, self.page_size)
+                chunk = jnp.asarray(prompt[None, lo:end])
+                logits, self._caches = self._jit_chunk(
+                    self.params, self._caches, chunk, btab_dev[:, :n_live],
+                    jnp.int32(lo))
+                jax.block_until_ready(logits)
+                clock.charge("prefill")     # each chunk is a prefill dispatch
             chunks += 1
         return logits, chunks
 
@@ -299,8 +307,9 @@ class PagedEngine(_EngineBase):
         clock = self.clock
         t0 = clock.now()
         key = jax.random.PRNGKey(self.seed)
-        self._caches = self.cache_init(self.num_pages, self.page_size)
-        alloc = PageAllocator(self.num_pages, self.page_size)
+        with TraceAnnotation(N.POOL_INIT):
+            self._caches = self.cache_init(self.num_pages, self.page_size)
+            alloc = PageAllocator(self.num_pages, self.page_size)
         radix = RadixCache(alloc) if self.prefix_cache else None
         inj = FaultInjector(self.fault_plan) if self.fault_plan else None
         stats = PoolStats()
@@ -320,7 +329,6 @@ class PagedEngine(_EngineBase):
         decode_steps = prefills = peak_conc = blocked = 0
         lookups = hits = tokens_saved = 0
         preempt_events = requeues = 0
-        qd_samples: List[int] = []
         step = -1                        # engine step (admission or decode)
 
         def audit() -> None:
@@ -423,14 +431,8 @@ class PagedEngine(_EngineBase):
             audit()
             return True
 
-        while sched.queue or dw.active_host.any():
-            step += 1
-            qd_samples.append(sched.queue_depth())
-            if inj is not None:
-                inj.begin_step(step, alloc, clock)
-                audit()
-            # ---- Scheduler role: reap queued then active requests past SLO
-            now_rel = clock.now() - t0
+        def reap(now_rel: float) -> None:
+            """Time out queued and active requests past their deadline."""
             for r in sched.reap_queued(now_rel):
                 m = metrics[r.rid]
                 m.outcome = "timed_out"
@@ -453,134 +455,158 @@ class PagedEngine(_EngineBase):
                     m.tokens = cum
                     m.finish_s = now_rel
                 audit()
-            # ---- admission: lane + arrived request + enough pages; a
-            # higher-priority arrival may preempt to make room for both
-            while sched.queue:
-                now_rel = clock.now() - t0
-                req = sched.peek_best(now_rel)
-                if req is None:
-                    break
-                if dw.active_host.all() and not try_preempt(req):
-                    break
-                if inj is not None and inj.refuse_alloc():
-                    blocked += 1     # transient injected refusal: retry
-                    break            # next engine step
-                # PrefillWorker role: reserve under the prefill owner key
-                got = pw.reserve(req, alloc, radix)
-                if radix is not None:
-                    lookups += 1
-                while got is None and try_preempt(req):
-                    got = pw.reserve(req, alloc, radix)
-                if got is None:
-                    blocked += 1     # queue head waits for retirements
-                    break
-                pages, s0 = got
-                sched.take(req)
-                prompt_np = np.asarray(req.prompt, np.int32)
-                prompt_of[req.rid] = prompt_np
-                slot = dw.free_lane()
-                m = metrics[req.rid]
-                base = len(partial.get(req.rid, ()))
-                m.admitted_s = clock.now() - t0
-                m.slot = slot
-                m.cached_prompt_tokens = s0
-                if s0 > 0:
-                    hits += 1
-                    tokens_saved += s0
-                peak_conc = max(peak_conc, alloc.num_owners)
-                btab_row = np.zeros(self.npag_max, np.int32)
-                btab_row[:len(pages)] = pages
-                btab_dev = jnp.asarray(btab_row)[None]
-                try:
-                    if inj is not None:
-                        inj.check_prefill()
-                    logits, chunks = pw.prefill(
-                        prompt_np, btab_dev, clock, start=s0)
-                except InjectedFault:
-                    # contain the fault to this request: give back its
-                    # pages (un-prefilled — check_prefill fires before
-                    # any chunk writes) and retry or fail it alone
-                    handoff.abort(req.rid)
-                    audit()
-                    requeue_or_fail(req.rid, np.zeros(0, np.int32),
-                                    clock.now() - t0, "failed")
-                    inj.note_prefill_resolved(step)
-                    continue
-                prefills += chunks
-                if radix is not None:   # index the prompt's full pages
-                    radix.insert(prompt_np, pages)
-                key, sub = jax.random.split(key)
-                tok0 = _sample_tokens(logits[:, -1:], sub, self.greedy)
-                if base == 0:
-                    m.first_token_s = clock.now() - t0
-                m.new_tokens = base + 1
-                done0 = req.max_new_tokens == 1
-                if self.eos_id is not None:
-                    done0 = done0 or int(tok0[0, 0]) == self.eos_id
-                # PageHandoff role: decode takes ownership of the pages.
-                # Interleaved, the lane picks the request up in the same
-                # engine step, so handoff latency is zero by construction
-                # (the disaggregated engine measures the real queue-wait)
-                handoff.transfer(req.rid)
-                handoff.latencies_s.append(0.0)
-                dw.admit(tok0, btab_dev[0], slot, req.prompt_len,
-                         req.max_new_tokens, not done0)
-                dw.slot_tokens[slot] += 1
-                admissions += 1
-                dw.admit_seq[slot] = admissions
+
+        while sched.queue or dw.active_host.any():
+            step += 1
+            with TraceAnnotation(N.STEP, step=step):
                 if inj is not None:
-                    inj.note_admission(step)
-                if done0:
-                    m.finished = True
-                    m.outcome = "completed"
-                    m.finish_s = clock.now() - t0
-                    m.tokens = cumulative(
-                        req.rid, np.asarray([int(tok0[0, 0])], np.int32))
-                    self._release_pages(alloc, req.rid)
+                    inj.begin_step(step, alloc, clock)
                     audit()
-                else:
-                    dw.active_host[slot] = True
-                    dw.slot_rid[slot] = req.rid
-            if not dw.active_host.any():
-                if sched.queue:
-                    # pool idle until the next arrival; when admission is
-                    # blocked by an injected fault instead, fall through —
-                    # the engine-step counter keeps advancing so timed
-                    # faults (pressure windows, refusals) can drain
-                    clock.wait_until(t0 + sched.next_arrival())
-                    continue
-                break
-            # ---- DecodeWorker role: one fused step over all lanes
-            t_step = clock.now()
-            dw.note_step_start(t_step - t0)
-            key, sub = jax.random.split(key)
-            new_active, ncounts = dw.step(sub)
-            dur = clock.now() - t_step
-            dw.busy_s += dur
-            decode_steps += 1
-            for s in np.flatnonzero(dw.active_host):
-                rid = dw.slot_rid[s]
-                m = metrics[rid]
-                base = len(partial.get(rid, ()))
-                m.token_latencies_s.append(dur)
-                m.new_tokens = base + int(ncounts[s])
-                dw.slot_tokens[s] += 1
-                if not new_active[s]:         # EOS or budget: free pages
-                    m.finished = True
-                    m.outcome = "completed"
-                    m.finish_s = clock.now() - t0
-                    gen = np.asarray(dw.state["tokbuf"][s, :int(ncounts[s])])
-                    m.tokens = cumulative(rid, gen)
+                # ---- Scheduler role: reap queued then active requests
+                # past SLO
+                with TraceAnnotation(N.REAP):
+                    now_rel = clock.now() - t0
+                    reap(now_rel)
+                # ---- admission: lane + arrived request + enough pages; a
+                # higher-priority arrival may preempt to make room for both
+                while sched.queue:
+                    now_rel = clock.now() - t0
+                    req = sched.peek_best(now_rel)
+                    if req is None:
+                        break
+                    if dw.active_host.all() and not try_preempt(req):
+                        break
+                    if inj is not None and inj.refuse_alloc():
+                        blocked += 1     # transient injected refusal: retry
+                        break            # next engine step
+                    # PrefillWorker role: reserve under the prefill owner key
+                    got = pw.reserve(req, alloc, radix)
                     if radix is not None:
-                        index_sequence(rid, gen)
-                    self._release_pages(alloc, rid)
-                    audit()
-                    dw.slot_rid[s] = None
-            dw.active_host = new_active.copy() & dw.active_host
-            dw.note_step_end(clock.now() - t0)
-            live = sum(plen_of[dw.slot_rid[s]] + int(ncounts[s])
-                       for s in np.flatnonzero(dw.active_host))
-            stats.sample(alloc, live)
+                        lookups += 1
+                    while got is None and try_preempt(req):
+                        got = pw.reserve(req, alloc, radix)
+                    if got is None:
+                        blocked += 1     # queue head waits for retirements
+                        break
+                    pages, s0 = got
+                    with TraceAnnotation(N.ADMIT, rid=req.rid,
+                                         prompt_len=req.prompt_len,
+                                         cached=s0):
+                        sched.take(req)
+                        prompt_np = np.asarray(req.prompt, np.int32)
+                        prompt_of[req.rid] = prompt_np
+                        slot = dw.free_lane()
+                        m = metrics[req.rid]
+                        base = len(partial.get(req.rid, ()))
+                        m.admitted_s = clock.now() - t0
+                        m.slot = slot
+                        m.cached_prompt_tokens = s0
+                        if s0 > 0:
+                            hits += 1
+                            tokens_saved += s0
+                        peak_conc = max(peak_conc, alloc.num_owners)
+                        btab_row = np.zeros(self.npag_max, np.int32)
+                        btab_row[:len(pages)] = pages
+                        btab_dev = jnp.asarray(btab_row)[None]
+                        try:
+                            if inj is not None:
+                                inj.check_prefill()
+                            logits, chunks = pw.prefill(
+                                prompt_np, btab_dev, clock, rid=req.rid,
+                                start=s0)
+                        except InjectedFault:
+                            # contain the fault to this request: give back its
+                            # pages (un-prefilled — check_prefill fires
+                            # before any chunk writes) and retry or fail it
+                            # alone
+                            handoff.abort(req.rid)
+                            audit()
+                            requeue_or_fail(req.rid, np.zeros(0, np.int32),
+                                            clock.now() - t0, "failed")
+                            inj.note_prefill_resolved(step)
+                            continue
+                        prefills += chunks
+                        if radix is not None:   # index the prompt's full pages
+                            radix.insert(prompt_np, pages)
+                        key, sub = jax.random.split(key)
+                        tok0 = _sample_tokens(logits[:, -1:], sub, self.greedy)
+                        if base == 0:
+                            m.first_token_s = clock.now() - t0
+                        m.new_tokens = base + 1
+                        done0 = req.max_new_tokens == 1
+                        if self.eos_id is not None:
+                            done0 = done0 or int(tok0[0, 0]) == self.eos_id
+                        # PageHandoff role: decode takes ownership of the
+                        # pages. Interleaved, the lane picks the request up
+                        # in the same engine step: there is no handoff wait
+                        # to record (the disaggregated engine measures the
+                        # real queue-wait)
+                        handoff.transfer(req.rid)
+                        dw.admit(tok0, btab_dev[0], slot, req.prompt_len,
+                                 req.max_new_tokens, not done0)
+                        dw.slot_tokens[slot] += 1
+                        admissions += 1
+                        dw.admit_seq[slot] = admissions
+                        if inj is not None:
+                            inj.note_admission(step)
+                        if done0:
+                            m.finished = True
+                            m.outcome = "completed"
+                            m.finish_s = clock.now() - t0
+                            m.tokens = cumulative(
+                                req.rid,
+                                np.asarray([int(tok0[0, 0])], np.int32))
+                            self._release_pages(alloc, req.rid)
+                            audit()
+                        else:
+                            dw.active_host[slot] = True
+                            dw.slot_rid[slot] = req.rid
+                if not dw.active_host.any():
+                    if sched.queue:
+                        # pool idle until the next arrival; when admission
+                        # is blocked by an injected fault instead, fall
+                        # through — the engine-step counter keeps advancing
+                        # so timed faults (pressure windows, refusals) can
+                        # drain
+                        with TraceAnnotation(N.WAIT_FOR_ARRIVAL):
+                            clock.wait_until(t0 + sched.next_arrival())
+                        continue
+                    break
+                # ---- DecodeWorker role: one fused step over all lanes
+                t_step = clock.now()
+                dw.note_step_start(t_step - t0)
+                key, sub = jax.random.split(key)
+                new_active, ncounts = dw.step(sub)
+                dur = clock.now() - t_step
+                dw.busy_s += dur
+                decode_steps += 1
+                lanes = np.flatnonzero(dw.active_host)
+                for s in lanes:
+                    rid = dw.slot_rid[s]
+                    m = metrics[rid]
+                    base = len(partial.get(rid, ()))
+                    m.token_latencies_s.append(dur)
+                    m.new_tokens = base + int(ncounts[s])
+                    dw.slot_tokens[s] += 1
+                for s in lanes[~new_active[lanes]]:  # EOS or budget
+                    rid, n = dw.slot_rid[s], int(ncounts[s])
+                    with TraceAnnotation(N.RETIRE, rid=rid, tokens=n):
+                        m = metrics[rid]
+                        m.finished = True
+                        m.outcome = "completed"
+                        m.finish_s = clock.now() - t0
+                        gen = np.asarray(dw.state["tokbuf"][s, :n])
+                        m.tokens = cumulative(rid, gen)
+                        if radix is not None:
+                            index_sequence(rid, gen)
+                        self._release_pages(alloc, rid)
+                        audit()
+                        dw.slot_rid[s] = None
+                dw.active_host = new_active.copy() & dw.active_host
+                dw.note_step_end(clock.now() - t0)
+                live = sum(plen_of[dw.slot_rid[s]] + int(ncounts[s])
+                           for s in np.flatnonzero(dw.active_host))
+                stats.sample(alloc, live)
         self._caches = None          # free the pool between runs
         return ServeReport(
             metrics=[metrics[r.rid] for r in (*reqs, *rejected)],
@@ -607,10 +633,6 @@ class PagedEngine(_EngineBase):
             fault_recoveries=inj.recoveries if inj else 0,
             fault_recovery_steps=inj.recovery_steps() if inj else [],
             handoffs=handoff.handoffs,
-            handoff_latencies_s=list(handoff.latencies_s),
-            queue_depth_peak=max(qd_samples, default=0),
-            queue_depth_mean=(float(sum(qd_samples) / len(qd_samples))
-                              if qd_samples else 0.0),
             decode_stalls_s=list(dw.stalls_s))
 
 

@@ -42,7 +42,9 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
+from repro.runtime import trace_names as N
 from repro.serving.pages import PageAllocator, PoolInvariantError
 from repro.serving.request import Request
 
@@ -294,12 +296,13 @@ class PrefillWorker:
         return self.engine._reserve_pages(req, alloc, radix,
                                           owner=prefill_owner(req.rid))
 
-    def prefill(self, prompt: np.ndarray, btab_dev, clock, *,
+    def prefill(self, prompt: np.ndarray, btab_dev, clock, *, rid: int,
                 start: int = 0):
-        """Chunk-prefill ``prompt[start:]`` into the reserved pages;
-        returns (last chunk's logits, chunks dispatched)."""
-        logits, chunks = self.engine._chunked_prefill(prompt, btab_dev,
-                                                      clock, start=start)
+        """Chunk-prefill request ``rid``'s ``prompt[start:]`` into the
+        reserved pages; returns (last chunk's logits, chunks
+        dispatched)."""
+        logits, chunks = self.engine._chunked_prefill(
+            prompt, btab_dev, clock, rid=rid, start=start)
         self.dispatches += chunks
         return logits, chunks
 
@@ -379,13 +382,16 @@ class DecodeWorker:
         engine's pool step on the shared caches, blocks, charges the
         clock. Returns host copies of (new_active, ncounts)."""
         eng = self.engine
-        eng._caches, self.state = eng._pool_step(eng.params, eng._caches,
-                                                 self.state, key)
-        jax.block_until_ready(self.state["active"])
-        eng.clock.charge("decode")
-        self.steps += 1
-        return (np.asarray(self.state["active"]),
-                np.asarray(self.state["ncount"]))
+        with TraceAnnotation(N.DECODE, step=self.steps,
+                             lanes=int(self.active_host.sum())):
+            eng._caches, self.state = eng._pool_step(
+                eng.params, eng._caches, self.state, key)
+            jax.block_until_ready(self.state["active"])
+            eng.clock.charge("decode")
+            self.steps += 1
+            with TraceAnnotation(N.LANE_STATE_READ):
+                return (np.asarray(self.state["active"]),
+                        np.asarray(self.state["ncount"]))
 
 
 __all__ = [
