@@ -265,6 +265,7 @@ def plan_paged_attention(dims: Dims, config: Dict[str, int]) -> List[Plan]:
     B, Hq, Hkv = int(dims["B"]), int(dims["Hq"]), int(dims["Hkv"])
     D, P, ps = int(dims["D"]), int(dims["P"]), int(dims["ps"])
     npag = int(dims["npag"])
+    L = int(dims.get("L", 1))  # layers in the stacked pool the kernel reads
     dt = str(dims.get("dtype", "float32"))
     path = "src/repro/kernels/paged_attention.py"
     if Hkv <= 0 or Hq % Hkv:
@@ -277,27 +278,32 @@ def plan_paged_attention(dims: Dims, config: Dict[str, int]) -> List[Plan]:
     # judges the tiling that would actually run
     ppb = max(1, min(int(config["pages_per_block"]), npag))
     nb = -(-npag // ppb)
-    # worst-case synthetic block table: every live entry points at the
-    # highest physical page, padding at the null page — the same bounds
-    # the scalar-prefetch index_map sees at runtime
+    # worst-case synthetic scalar-prefetch operands: every live table
+    # entry points at the highest physical page, padding at the null
+    # page, and the layer index is the last layer — the same bounds the
+    # index_map sees at runtime
     btab = np.zeros((B, nb * ppb), dtype=np.int64)
     btab[:, :npag] = P - 1
+    layer = L - 1
 
     def qmap(b, j):
         return (b, 0, 0)
 
     def kvmap(p):
         def index_map(b, j, p=p):
-            return (int(btab[b, j * ppb + p]), 0, 0, 0)
+            return (layer, int(btab[b, j * ppb + p]), 0, 0, 0)
 
         return index_map
 
-    pages_arr = (P, ps, Hkv, D)
+    # the stacked pool; the layer dim is squeezed from the block
+    pages_arr = (L, P, ps, Hkv, D)
     blocks = [Block("q", (B, Hq, D), (1, Hq, D), qmap, dt)]
     for side in ("k", "v"):
         for p in range(ppb):
             blocks.append(
-                Block(f"{side}_pages[{p}]", pages_arr, (1, ps, Hkv, D), kvmap(p), dt)
+                Block(
+                    f"{side}_pages[{p}]", pages_arr, (1, 1, ps, Hkv, D), kvmap(p), dt
+                )
             )
     blocks.append(Block("o", (B, Hq, D), (1, Hq, D), qmap, dt))
     # every query head scores against the whole flattened page
@@ -364,6 +370,9 @@ CANONICAL_DIMS: Dict[str, List[Dims]] = {
     ],
     "paged_attention_fwd": [
         dict(B=8, Hq=32, Hkv=8, D=128, P=512, ps=16, npag=128, dtype="float32"),
+        dict(
+            B=64, Hq=32, Hkv=8, D=128, L=10, P=7800, ps=16, npag=256, dtype="bfloat16"
+        ),
     ],
 }
 

@@ -114,22 +114,24 @@ def wkv6(q, k, v, ld, u=None, initial_state=None, *,
 
 
 @partial(jax.jit, static_argnames=("pages_per_block", "interpret"))
-def _paged_attention_jit(q, k_pages, v_pages, block_tables, lengths, *,
-                         pages_per_block: int, interpret: bool):
+def _paged_attention_jit(q, k_pages, v_pages, block_tables, lengths, layer,
+                         *, pages_per_block: int, interpret: bool):
     return paged_attention_fwd(q, k_pages, v_pages, block_tables, lengths,
-                               pages_per_block=pages_per_block,
+                               layer, pages_per_block=pages_per_block,
                                interpret=interpret)
 
 
-def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
-                           pages_per_block: int | None = None):
-    """Block-table paged decode attention (no backward: decode only).
-    pages_per_block None = auto (tuned cache -> 1)."""
+def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
+                           layer=0, *, pages_per_block: int | None = None):
+    """Block-table paged decode attention (no backward: decode only) over
+    the stacked pools (L, P, page_size, Hkv, D) at ``layer``, or over one
+    layer's pool (P, page_size, Hkv, D). pages_per_block None = auto
+    (tuned cache -> 1)."""
     ppb = tuning.resolve_paged_pages_per_block(
-        pages_per_block, q_shape=q.shape, pages_shape=k_pages.shape,
+        pages_per_block, q_shape=q.shape, pages_shape=k_pages.shape[-4:],
         n_pages=block_tables.shape[1], dtype=q.dtype)
     return _paged_attention_jit(q, k_pages, v_pages, block_tables, lengths,
-                                pages_per_block=ppb,
+                                layer, pages_per_block=ppb,
                                 interpret=interpret_mode())
 
 

@@ -8,10 +8,18 @@ operand, and each K/V BlockSpec's ``index_map`` reads the table to pick
 the physical page its DMA fetches — the pool never has to be gathered
 into a contiguous activation on the host side.
 
+The pool may be the model's whole stack of layers, ``(L, P, page_size,
+Hkv, D)``, with the layer to read given as one more scalar-prefetch
+operand: the layer scan then hands the kernel the pool it carries, and
+no layer's pool is ever sliced out into an operand of its own. A
+single-layer pool ``(P, page_size, Hkv, D)`` is the case ``L = 1``,
+layer 0.
+
 Tiling: grid ``(B, n_blocks)`` with the page-block dim innermost and
 sequential ("arbitrary"), so the online-softmax accumulators live in
 VMEM scratch across page blocks. Each K/V block is one whole page with
-all its KV heads, ``(1, page_size, Hkv, D)``, so one DMA moves a page.
+all its KV heads, ``(page_size, Hkv, D)`` of one layer, so one DMA moves
+a page.
 The tunable tile parameter is ``pages_per_block``: how many pages one
 grid step consumes. It is realised by passing the pool
 ``pages_per_block`` times with offset index maps — each copy is an
@@ -41,9 +49,10 @@ from repro.kernels import tuning
 NEG_INF = -1e30
 
 
-def _paged_kernel(btab_ref, len_ref, q_ref, *refs, scale: float, ps: int,
-                  ppb: int, nb: int, hkv: int, g: int):
+def _paged_kernel(btab_ref, len_ref, layer_ref, q_ref, *refs, scale: float,
+                  ps: int, ppb: int, nb: int, hkv: int, g: int):
     """refs = k_ref x ppb, v_ref x ppb, o_ref, m_scr, l_scr, acc_scr.
+    ``layer_ref`` is read only by the K/V index maps.
 
     A K/V block is one whole page, every KV head: (1, ps, Hkv, D). It is
     flattened to (ps * Hkv, D) rows, row c holding token c // Hkv of KV
@@ -98,10 +107,12 @@ def _paged_kernel(btab_ref, len_ref, q_ref, *refs, scale: float, ps: int,
                     jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
-def paged_attention_fwd(q, k_pages, v_pages, block_tables, lengths, *,
-                        pages_per_block: int | None = None,
+def paged_attention_fwd(q, k_pages, v_pages, block_tables, lengths,
+                        layer=0, *, pages_per_block: int | None = None,
                         interpret: bool = False):
-    """q: (B, 1, Hq, D); k_pages/v_pages: (P, page_size, Hkv, D);
+    """q: (B, 1, Hq, D); k_pages/v_pages: the stacked pools (L, P,
+    page_size, Hkv, D), read at ``layer`` (a scalar, traced or not), or
+    one layer's pool (P, page_size, Hkv, D) with ``layer`` 0;
     block_tables: (B, n_pages) int32 physical page ids (logical order,
     padded with the null page 0); lengths: (B,) int32 valid KV tokens.
     Returns (B, 1, Hq, D). pages_per_block None = auto (tuned cache)."""
@@ -110,11 +121,13 @@ def paged_attention_fwd(q, k_pages, v_pages, block_tables, lengths, *,
         raise ValueError(
             f"paged decode attention takes one query token per row, got "
             f"q.shape={q.shape}")
-    P, ps, Hkv, _ = k_pages.shape
+    if k_pages.ndim == 4:                     # one layer: L = 1, no copy
+        k_pages, v_pages = k_pages[None], v_pages[None]
+    ps, Hkv = k_pages.shape[2:4]
     npag = block_tables.shape[1]
     g = Hq // Hkv
     ppb = tuning.resolve_paged_pages_per_block(
-        pages_per_block, q_shape=q.shape, pages_shape=k_pages.shape,
+        pages_per_block, q_shape=q.shape, pages_shape=k_pages.shape[1:],
         n_pages=npag, dtype=q.dtype)
     nb = -(-npag // ppb)                      # grid steps over page blocks
     pad = nb * ppb - npag
@@ -122,21 +135,24 @@ def paged_attention_fwd(q, k_pages, v_pages, block_tables, lengths, *,
     if pad:
         btab = jnp.pad(btab, ((0, 0), (0, pad)))      # null-page padding
     lengths = jnp.asarray(lengths, jnp.int32).reshape(B)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    def q_map(b, j, bt, ln):
+    def q_map(b, j, bt, ln, ly):
         return (b, 0, 0)
 
     def kv_map(p):
-        # the in-kernel gather: physical page id straight from the table
-        def index_map(b, j, bt, ln, p=p):
-            return (bt[b, j * ppb + p], 0, 0, 0)
+        # the in-kernel gather: physical page id straight from the table,
+        # in the layer the scalar operand names
+        def index_map(b, j, bt, ln, ly, p=p):
+            return (ly[0], bt[b, j * ppb + p], 0, 0, 0)
         return index_map
 
-    # whole pages, all KV heads: the last two block dims equal the pool's
-    # (Hkv, D), which Mosaic accepts for any head count
-    kv_spec = [pl.BlockSpec((1, ps, Hkv, D), kv_map(p)) for p in range(ppb)]
+    # whole pages, all KV heads, of one layer: the last two block dims
+    # equal the pool's (Hkv, D), which Mosaic accepts for any head count
+    kv_spec = [pl.BlockSpec((None, 1, ps, Hkv, D), kv_map(p))
+               for p in range(ppb)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, nb),
         in_specs=[pl.BlockSpec((1, Hq, D), q_map), *kv_spec, *kv_spec],
         out_specs=pl.BlockSpec((1, Hq, D), q_map),
@@ -154,6 +170,6 @@ def paged_attention_fwd(q, k_pages, v_pages, block_tables, lengths, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(btab, lengths, q.reshape(B, Hq, D), *([k_pages] * ppb),
+    )(btab, lengths, layer, q.reshape(B, Hq, D), *([k_pages] * ppb),
       *([v_pages] * ppb))
     return out.reshape(B, 1, Hq, D)

@@ -167,20 +167,24 @@ def decode_attention_simple(q, k_cache, v_cache, cache_len) -> jnp.ndarray:
 
 
 def paged_decode_attention_ref(q, k_pages, v_pages, block_tables,
-                               lengths) -> jnp.ndarray:
+                               lengths, layer=0) -> jnp.ndarray:
     """Reference paged decode attention: gather the per-row pages into a
     contiguous logical cache and reuse :func:`decode_attention_simple`.
-    q:(B,1,Hq,D); k_pages/v_pages:(P,ps,Hkv,D); block_tables:(B,npag)
-    physical page ids in logical order; lengths:(B,) valid KV tokens.
+    q:(B,1,Hq,D); k_pages/v_pages: the stacked pools (L,P,ps,Hkv,D), read
+    at ``layer``, or one layer's pool (P,ps,Hkv,D) with ``layer`` 0;
+    block_tables:(B,npag) physical page ids in logical order; lengths:(B,)
+    valid KV tokens.
 
     Gathered logical order == position order, so the masked positions and
     the softmax summation order match both the monolithic decode path and
     the Pallas kernel (which gathers inside the kernel instead)."""
+    if k_pages.ndim == 4:
+        k_pages, v_pages = k_pages[None], v_pages[None]
     B = q.shape[0]
-    P, ps, Hkv, D = k_pages.shape
+    ps, Hkv, D = k_pages.shape[2:]
     npag = block_tables.shape[1]
-    k = k_pages[block_tables].reshape(B, npag * ps, Hkv, D)
-    v = v_pages[block_tables].reshape(B, npag * ps, Hkv, D)
+    k = k_pages[layer, block_tables].reshape(B, npag * ps, Hkv, D)
+    v = v_pages[layer, block_tables].reshape(B, npag * ps, Hkv, D)
     return decode_attention_simple(q, k, v, lengths)
 
 

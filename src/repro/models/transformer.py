@@ -489,27 +489,31 @@ def layer_decode(p, x, cache, pos, cfg: ModelConfig, rt: Runtime,
     return x + h, new_cache
 
 
-def _paged_attend(q, k_pool, v_pool, block_tables, lengths, rt: Runtime):
-    """Backend switch for block-table attention: the Pallas kernel (with
-    its in-kernel page gather) for backend='pallas', the gather-then-
-    decode_attention_simple reference everywhere else."""
+def _paged_attend(q, k_pools, v_pools, block_tables, lengths, layer,
+                  rt: Runtime):
+    """Backend switch for block-table attention over the stacked pools at
+    ``layer``: the Pallas kernel (with its in-kernel page gather) for
+    backend='pallas', the gather-then-decode_attention_simple reference
+    everywhere else."""
     if rt.attention_backend == "pallas":
         from repro.kernels import ops as kops
         return kops.paged_decode_attention(
-            q, k_pool, v_pool, block_tables, lengths,
+            q, k_pools, v_pools, block_tables, lengths, layer,
             pages_per_block=rt.paged_pages_per_block)
-    return attn_mod.paged_decode_attention_ref(q, k_pool, v_pool,
-                                               block_tables, lengths)
+    return attn_mod.paged_decode_attention_ref(q, k_pools, v_pools,
+                                               block_tables, lengths, layer)
 
 
-def layer_decode_paged(p, x, cache, pos, block_tables, cfg: ModelConfig,
-                       rt: Runtime):
-    """Single-token step against the paged KV pool. x: (B,1,d); cache:
-    this layer's {"k","v"} pools (P, page_size, Hkv, D) — no batch axis;
-    pos: (B,) per-row positions; block_tables: (B, n_pages) physical page
-    ids in logical order (retired rows all-null). Each row writes its new
-    K/V at (table[pos // page_size], pos % page_size) — rows own disjoint
-    pages, so the scatter never races."""
+def layer_decode_paged(p, x, pools, layer, pos, block_tables,
+                       cfg: ModelConfig, rt: Runtime):
+    """Single-token step against the paged KV pool. x: (B,1,d); pools:
+    the stacked {"k","v"} pools (L, P, page_size, Hkv, D) — no batch
+    axis — of which this is layer ``layer``; pos: (B,) per-row positions;
+    block_tables: (B, n_pages) physical page ids in logical order
+    (retired rows all-null). Each row writes its new K/V at (layer,
+    table[pos // page_size], pos % page_size) — rows own disjoint pages,
+    so the scatter never races — and the kernel reads the same stacked
+    pool at ``layer``: no layer's pool is sliced out or written back."""
     pos = jnp.asarray(pos)
     h_in = apply_norm(p["norm1"], x, cfg.norm)
     with jax.named_scope(ATTENTION):
@@ -519,46 +523,61 @@ def layer_decode_paged(p, x, cache, pos, block_tables, cfg: ModelConfig,
                          jnp.broadcast_to(pos_b[:, None],
                                           (x.shape[0], 3, 1)))
     with jax.named_scope(KV_WRITE):
-        ps = cache["k"].shape[1]
+        ps = pools["k"].shape[2]
         bidx = jnp.arange(x.shape[0])
         pages = block_tables[bidx, pos // ps]
         offs = pos % ps
-        k_pool = cache["k"].at[pages, offs].set(k[:, 0])
-        v_pool = cache["v"].at[pages, offs].set(v[:, 0])
+        k_pools = pools["k"].at[layer, pages, offs].set(k[:, 0])
+        v_pools = pools["v"].at[layer, pages, offs].set(v[:, 0])
     with jax.named_scope(ATTENTION):
-        o = _paged_attend(q, k_pool, v_pool, block_tables, pos + 1, rt)
+        o = _paged_attend(q, k_pools, v_pools, block_tables, pos + 1, layer,
+                          rt)
         h = o.reshape(*x.shape[:-1], -1) @ p["attn"]["wo"]
     x = x + h
     h, _ = _ffn(p, apply_norm(p["norm2"], x, cfg.norm), cfg, rt)
-    return x + h, {"k": k_pool, "v": v_pool}
+    return x + h, {"k": k_pools, "v": v_pools}
 
 
-def stack_decode_paged(stacked, x, caches, pos, block_tables,
-                       cfg: ModelConfig, rt: Runtime):
-    """Scan paged decode over layers; block tables are shared across
-    layers (one logical address space, L physical pools)."""
+def _scan_paged(layer_fn, stacked, x, pools):
+    """Scan ``layer_fn(p_layer, layer, x, pools) -> (x, pools)`` over the
+    layer stack with the stacked pools in the carry, so that each layer
+    updates them in place at its own index: pools passed as scan ``xs``
+    and ``ys`` would be sliced out and stacked back, a copy of the whole
+    pool every step."""
+    L = jax.tree.leaves(stacked)[0].shape[0]
 
     def body(carry, xs):
-        p_layer, cache = xs
-        y, new_cache = layer_decode_paged(p_layer, carry, cache, pos,
-                                          block_tables, cfg, rt)
-        return y, new_cache
+        return layer_fn(*xs, *carry), None
 
     with jax.named_scope(LAYERS):
-        return jax.lax.scan(body, x, (stacked, caches))
+        (x, pools), _ = jax.lax.scan(body, (x, pools),
+                                     (stacked, jnp.arange(L)))
+    return x, pools
 
 
-def layer_prefill_chunk(p, x, cache, block_tables, positions,
+def stack_decode_paged(stacked, x, pools, pos, block_tables,
+                       cfg: ModelConfig, rt: Runtime):
+    """Paged decode through the layer stack; block tables are shared
+    across layers (one logical address space, L physical pools)."""
+    return _scan_paged(
+        lambda p, layer, y, pools: layer_decode_paged(
+            p, y, pools, layer, pos, block_tables, cfg, rt),
+        stacked, x, pools)
+
+
+def layer_prefill_chunk(p, x, pools, layer, block_tables, positions,
                         cfg: ModelConfig, rt: Runtime):
-    """Chunked-prefill layer step: write this chunk's K/V into the paged
-    pool, then attend causally over the *gathered* logical history (pages
-    written by earlier chunks plus this one). x: (B, C, d); positions:
-    (C,) absolute token positions of the chunk.
+    """Chunked-prefill layer step: write this chunk's K/V into layer
+    ``layer`` of the stacked paged pools (L, P, page_size, Hkv, D), then
+    attend causally over the *gathered* logical history (pages written by
+    earlier chunks plus this one). x: (B, C, d); positions: (C,) absolute
+    token positions of the chunk.
 
     The chunk is small and prefill is compute-bound, so the gather runs
     outside any kernel and the scores go through ``dense_attention`` with
     ``q_offset`` — the same masked-softmax math as the one-shot prefill,
-    summed in the same (logical-position) order."""
+    summed in the same (logical-position) order. The gather reads the
+    table's pages of this layer only, never a layer-sized slice."""
     h_in = apply_norm(p["norm1"], x, cfg.norm)
     with jax.named_scope(ATTENTION):
         q, k, v = attn_mod.project_qkv(p["attn"], h_in, h_in, cfg)
@@ -566,38 +585,34 @@ def layer_prefill_chunk(p, x, cache, block_tables, positions,
                          else jnp.broadcast_to(positions[None, None],
                                                (1, 3, positions.shape[0])))
     B, C = x.shape[0], x.shape[1]
-    ps = cache["k"].shape[1]
+    ps = pools["k"].shape[2]
     npag = block_tables.shape[1]
     with jax.named_scope(KV_WRITE):
         pages = jnp.take(block_tables, positions // ps, axis=1)  # (B, C)
         offs = jnp.broadcast_to((positions % ps)[None], (B, C))
-        k_pool = cache["k"].at[pages, offs].set(k)
-        v_pool = cache["v"].at[pages, offs].set(v)
+        k_pools = pools["k"].at[layer, pages, offs].set(k)
+        v_pools = pools["v"].at[layer, pages, offs].set(v)
     with jax.named_scope(ATTENTION):
-        k_all = k_pool[block_tables].reshape(B, npag * ps, *k.shape[2:])
-        v_all = v_pool[block_tables].reshape(B, npag * ps, *v.shape[2:])
+        k_all = k_pools[layer, block_tables].reshape(B, npag * ps,
+                                                     *k.shape[2:])
+        v_all = v_pools[layer, block_tables].reshape(B, npag * ps,
+                                                     *v.shape[2:])
         o = attn_mod.dense_attention(q, k_all, v_all, causal=True,
                                      q_offset=positions[0])
         h = o.reshape(*x.shape[:-1], -1) @ p["attn"]["wo"]
     x = x + h
     h, _ = _ffn(p, apply_norm(p["norm2"], x, cfg.norm), cfg, rt)
-    return x + h, {"k": k_pool, "v": v_pool}
+    return x + h, {"k": k_pools, "v": v_pools}
 
 
-def stack_prefill_chunk(stacked, x, caches, block_tables, positions,
+def stack_prefill_chunk(stacked, x, pools, block_tables, positions,
                         cfg: ModelConfig, rt: Runtime):
-    """Scan one prompt chunk through the layer stack, threading the paged
-    pools as scan xs/ys."""
-
-    def body(carry, xs):
-        p_layer, cache = xs
-        y, new_cache = layer_prefill_chunk(p_layer, carry, cache,
-                                           block_tables, positions, cfg,
-                                           rt)
-        return y, new_cache
-
-    with jax.named_scope(LAYERS):
-        return jax.lax.scan(body, x, (stacked, caches))
+    """One prompt chunk through the layer stack, the stacked paged pools
+    in the scan's carry."""
+    return _scan_paged(
+        lambda p, layer, y, pools: layer_prefill_chunk(
+            p, y, pools, layer, block_tables, positions, cfg, rt),
+        stacked, x, pools)
 
 
 def stack_decode(stacked, x, caches, pos, cfg: ModelConfig, rt: Runtime,

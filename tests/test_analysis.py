@@ -214,7 +214,9 @@ def test_rmsnorm_explicit_rows_not_clamped():
 
 
 def test_paged_oversized_pages_per_block_rejected():
-    dims = dict(B=8, Hq=32, Hkv=8, D=128, P=512, ps=16, npag=512, dtype="float32")
+    dims = dict(
+        B=8, Hq=32, Hkv=8, D=128, L=10, P=512, ps=16, npag=512, dtype="float32"
+    )
     findings = kernel_lint.check_config(
         "paged_attention_fwd", dims, {"pages_per_block": 512}, "tpu"
     )
@@ -222,13 +224,38 @@ def test_paged_oversized_pages_per_block_rejected():
 
 
 def test_paged_default_accepted():
-    dims = dict(B=8, Hq=32, Hkv=8, D=128, P=512, ps=16, npag=128, dtype="float32")
+    dims = dict(
+        B=8, Hq=32, Hkv=8, D=128, L=10, P=512, ps=16, npag=128, dtype="float32"
+    )
     assert (
         kernel_lint.check_config(
             "paged_attention_fwd", dims, tuning.DEFAULTS["paged_attention_fwd"], "tpu"
         )
         == []
     )
+
+
+@pytest.mark.parametrize("layers", [1, 10])
+def test_paged_plan_reads_the_stacked_pool_at_its_layer(layers):
+    """The plan mirrors the shipped kernel: K/V blocks of one page of one
+    layer on the stacked (L, P, ps, Hkv, D) pool, addressed through the
+    layer and block-table scalar operands, and RK004 sees the layer axis."""
+    dims = dict(
+        B=4, Hq=32, Hkv=8, D=128, L=layers, P=64, ps=16, npag=8, dtype="float32"
+    )
+    (plan,) = kernel_lint.plan_paged_attention(dims, {"pages_per_block": 2})
+    pages = [b for b in plan.blocks if "_pages[" in b.name]
+    assert len(pages) == 4
+    for blk in pages:
+        assert blk.array_shape == (layers, 64, 16, 8, 128)
+        assert blk.block_shape == (1, 1, 16, 8, 128)
+        assert blk.index_map(3, 3) == (layers - 1, 63, 0, 0, 0)
+    caps = tuning.capabilities("tpu")
+    assert kernel_lint._check_plan(plan, caps) == []
+    inner = pages[0].index_map
+    pages[0].index_map = lambda b, j: (inner(b, j)[0] + 1,) + inner(b, j)[1:]
+    rules = {f.rule for f in kernel_lint._check_plan(plan, caps)}
+    assert rules == {"RK004"}
 
 
 def test_unsupported_dtype_rejected():

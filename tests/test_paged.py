@@ -158,6 +158,45 @@ def test_paged_tuning_wiring():
     assert out.shape == (2, 1, 4, 16)
 
 
+@pytest.mark.parametrize("ppb", [1, 2])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_paged_kernel_reads_stacked_pool_at_layer(layer, ppb):
+    """On the stacked (L, P, ps, Hkv, D) pool the kernel reads the layer
+    its scalar operand names: it equals the reference on that layer's
+    pool alone, for lengths that end mid-page and on a page boundary."""
+    rng = np.random.default_rng(7)
+    B, Hq, Hkv, D, L, P, ps, npag = 4, 4, 2, 16, 3, 11, 4, 5
+    q = jnp.asarray(rng.standard_normal((B, 1, Hq, D)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((L, P, ps, Hkv, D)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((L, P, ps, Hkv, D)), jnp.float32)
+    bt = jnp.asarray(rng.integers(1, P, size=(B, npag)), jnp.int32)
+    lens = jnp.asarray([6, 8, 1, 20], jnp.int32)     # mid, edge, 1, full
+    ref = paged_decode_attention_ref(q, kp[layer], vp[layer], bt, lens)
+    out = paged_attention_fwd(q, kp, vp, bt, lens, jnp.int32(layer),
+                              pages_per_block=ppb, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=1e-4)
+    stacked_ref = paged_decode_attention_ref(q, kp, vp, bt, lens,
+                                             jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(stacked_ref), np.asarray(ref))
+
+
+def test_paged_kernel_single_layer_pool_is_layer_zero():
+    """A 4-D pool is the stacked pool with L = 1 read at layer 0."""
+    from repro.kernels import ops
+
+    rng = np.random.default_rng(8)
+    q = jnp.asarray(rng.standard_normal((2, 1, 4, 16)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((9, 4, 2, 16)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((9, 4, 2, 16)), jnp.float32)
+    bt = jnp.asarray(rng.integers(1, 9, (2, 3)), jnp.int32)
+    lens = jnp.asarray([5, 12], jnp.int32)
+    flat = ops.paged_decode_attention(q, kp, vp, bt, lens)
+    stacked = ops.paged_decode_attention(q, kp[None], vp[None], bt, lens,
+                                         jnp.int32(0))
+    np.testing.assert_array_equal(np.asarray(flat), np.asarray(stacked))
+
+
 # ------------------------------------------------- model-level paged path
 def _tiny_serve(arch="granite-3-8b", span=24, slots=2):
     cfg = reduced(ARCHS[arch], layers=2, d_model=64, vocab=128, d_ff=128)
@@ -231,6 +270,131 @@ def test_paged_cache_init_rejects_unsupported_families():
     from repro.models import transformer as tfm
     with pytest.raises(ValueError, match="full-attention"):
         tfm.paged_cache_init(cfg, 2, 8, 4, jnp.float32)
+
+
+# The paged layer scan as it was before the stacked pools moved into the
+# scan's carry: each layer's pool sliced out as scan xs, updated, and
+# stacked back as ys. The carried scan must match it exactly.
+def _old_layer_decode_paged(p, x, cache, pos, block_tables, cfg, rt):
+    from repro.models import attention as attn_mod
+    from repro.models import transformer as tfm
+    from repro.models.layers import apply_norm
+
+    h_in = apply_norm(p["norm1"], x, cfg.norm)
+    q, k, v = attn_mod.project_qkv(p["attn"], h_in, h_in, cfg)
+    q, k = tfm._rope_q_k(cfg, q, k, pos.reshape(-1, 1))
+    ps = cache["k"].shape[1]
+    bidx = jnp.arange(x.shape[0])
+    pages = block_tables[bidx, pos // ps]
+    k_pool = cache["k"].at[pages, pos % ps].set(k[:, 0])
+    v_pool = cache["v"].at[pages, pos % ps].set(v[:, 0])
+    if rt.attention_backend == "pallas":
+        from repro.kernels import ops
+        o = ops.paged_decode_attention(q, k_pool, v_pool, block_tables,
+                                       pos + 1)
+    else:
+        o = paged_decode_attention_ref(q, k_pool, v_pool, block_tables,
+                                       pos + 1)
+    x = x + o.reshape(*x.shape[:-1], -1) @ p["attn"]["wo"]
+    h, _ = tfm._ffn(p, apply_norm(p["norm2"], x, cfg.norm), cfg, rt)
+    return x + h, {"k": k_pool, "v": v_pool}
+
+
+def _old_layer_prefill_chunk(p, x, cache, block_tables, positions, cfg, rt):
+    from repro.models import attention as attn_mod
+    from repro.models import transformer as tfm
+    from repro.models.layers import apply_norm
+
+    h_in = apply_norm(p["norm1"], x, cfg.norm)
+    q, k, v = attn_mod.project_qkv(p["attn"], h_in, h_in, cfg)
+    q, k = tfm._rope_q_k(cfg, q, k, positions[None])
+    B, C = x.shape[:2]
+    ps = cache["k"].shape[1]
+    npag = block_tables.shape[1]
+    pages = jnp.take(block_tables, positions // ps, axis=1)
+    offs = jnp.broadcast_to((positions % ps)[None], (B, C))
+    k_pool = cache["k"].at[pages, offs].set(k)
+    v_pool = cache["v"].at[pages, offs].set(v)
+    k_all = k_pool[block_tables].reshape(B, npag * ps, *k.shape[2:])
+    v_all = v_pool[block_tables].reshape(B, npag * ps, *v.shape[2:])
+    o = attn_mod.dense_attention(q, k_all, v_all, causal=True,
+                                 q_offset=positions[0])
+    x = x + o.reshape(*x.shape[:-1], -1) @ p["attn"]["wo"]
+    h, _ = tfm._ffn(p, apply_norm(p["norm2"], x, cfg.norm), cfg, rt)
+    return x + h, {"k": k_pool, "v": v_pool}
+
+
+def _old_stack_decode_paged(stacked, x, caches, pos, block_tables, cfg, rt):
+    def body(carry, xs):
+        p_layer, cache = xs
+        return _old_layer_decode_paged(p_layer, carry, cache, pos,
+                                       block_tables, cfg, rt)
+    return jax.lax.scan(body, x, (stacked, caches))
+
+
+def _old_stack_prefill_chunk(stacked, x, caches, block_tables, positions,
+                             cfg, rt):
+    def body(carry, xs):
+        p_layer, cache = xs
+        return _old_layer_prefill_chunk(p_layer, carry, cache, block_tables,
+                                        positions, cfg, rt)
+    return jax.lax.scan(body, x, (stacked, caches))
+
+
+def _serve_steps(model, params, pools, prompts, btab, chunk, steps):
+    """Two rows: a chunked prefill of both prompts, then greedy decode
+    steps. Returns every step's logits and the final pools."""
+    out = []
+    plen = prompts.shape[1]
+    for start in range(0, plen, chunk):
+        lg, pools = model.prefill_chunk(
+            params, pools, jnp.asarray(prompts[:, start:start + chunk]),
+            btab, jnp.int32(start))
+        out.append(lg)
+    tok = jnp.argmax(lg[:, -1:], -1).astype(jnp.int32)
+    pos = jnp.asarray([plen, plen - 2], jnp.int32)
+    for i in range(steps):
+        lg, pools = model.decode_step_paged(params, pools, tok, pos + i,
+                                            btab)
+        out.append(lg)
+        tok = jnp.argmax(lg, -1).astype(jnp.int32)
+    return out, pools
+
+
+@pytest.mark.parametrize("backend", ["dense", "pallas"])
+def test_carried_pool_scan_matches_sliced_scan(backend, monkeypatch):
+    """Chunked prefill and paged decode with the stacked pools in the
+    layer scan's carry give the very logits and pools of the sliced
+    xs/ys scan, on the reference and the (interpret-mode) Pallas paths,
+    over two chunks and several decode steps."""
+    from repro.models import transformer as tfm
+    from repro.models.model import build
+
+    cfg = reduced(ARCHS["granite-3-8b"], layers=3, d_model=64, vocab=128,
+                  d_ff=128)
+    model = build(cfg, tfm.Runtime(attention_backend=backend),
+                  param_dtype=jnp.float32)
+    params = model.init_params(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(4)
+    # a pool whose other pages hold noise: a read of a wrong layer or page
+    # would show in the logits
+    pools = jax.tree.map(
+        lambda a: jnp.asarray(rng.standard_normal(a.shape), a.dtype),
+        model.paged_cache_init(10, 4))
+    prompts = rng.integers(1, cfg.vocab_size, size=(2, 7)).astype(np.int32)
+    btab = jnp.asarray([[3, 7, 1], [9, 2, 5]], jnp.int32)
+    new_out, new_pools = _serve_steps(model, params, pools, prompts, btab,
+                                      chunk=4, steps=3)
+    monkeypatch.setattr(tfm, "stack_decode_paged", _old_stack_decode_paged)
+    monkeypatch.setattr(tfm, "stack_prefill_chunk", _old_stack_prefill_chunk)
+    old_out, old_pools = _serve_steps(model, params, pools, prompts, btab,
+                                      chunk=4, steps=3)
+    assert len(new_out) == 5
+    for a, b in zip(new_out, old_out):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for side in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(new_pools["layers"][side]),
+                                      np.asarray(old_pools["layers"][side]))
 
 
 # ------------------------------------------------------- paged engine

@@ -7,7 +7,10 @@ is needed: the topology is described, not attached. It is described inside
 a fixture, never while a module is imported, because only one process at a
 time may load the TPU library.
 """
+import dataclasses
+import math
 import os
+import re
 
 import pytest
 
@@ -16,7 +19,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_arch
-from repro.kernels import tuning
+from repro.kernels import ops, tuning
 from repro.kernels.flash_attention import (flash_attention_bwd,
                                            flash_attention_fwd)
 from repro.kernels.paged_attention import paged_attention_fwd
@@ -124,6 +127,75 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache,
     finally:
         tuning.clear_cache()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the paged serving step at granite's widths in bf16: 64 lanes, 16-token
+# pages, 7800 pages, 512-token prefill chunks, 256 live pages a row
+PAGED = dict(slots=64, page_size=16, num_pages=7800, chunk=512, npag=256)
+_POOL_OPS = ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice")
+
+
+def _paged_step(name, one_chip, layers):
+    """(compiled program, one layer's pool shape) of the paged decode step
+    or one prefill chunk at reduced depth, the pools donated."""
+    from repro.models.model import build
+    from repro.models.transformer import Runtime
+
+    cfg = dataclasses.replace(get_arch("granite-3-8b"), num_layers=layers)
+    model = build(cfg, Runtime(attention_backend="pallas"),
+                  param_dtype=jnp.bfloat16)
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = shaped(jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    pools = shaped(jax.eval_shape(lambda: model.paged_cache_init(
+        PAGED["num_pages"], PAGED["page_size"])))
+    B, npag = PAGED["slots"], PAGED["npag"]
+    if name == "decode":
+        fn, args = model.decode_step_paged, (i32(B, 1), i32(B), i32(B, npag))
+    else:
+        fn, args = model.prefill_chunk, (i32(1, PAGED["chunk"]),
+                                         i32(1, npag), i32())
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pools, *args).compile()
+    return compiled, pools["layers"]["k"].shape[1:]
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill_chunk"])
+def test_paged_step_updates_the_pool_in_place(name, one_chip,
+                                              no_compile_cache, monkeypatch,
+                                              tmp_path):
+    """The layer scan carries the stacked pools and each layer writes its
+    new keys and values into them in place: the compiled step neither
+    copies, slices out nor writes back a layer's pool or the whole pool,
+    and needs less scratch memory than one layer's pool."""
+    monkeypatch.setenv(tuning.ENV_VAR, str(tmp_path))
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    tuning.clear_cache()
+    layers = 2
+    try:
+        compiled, layer_pool = _paged_step(name, one_chip, layers)
+    finally:
+        tuning.clear_cache()
+    text = compiled.as_text()
+    if name == "decode":
+        assert "tpu_custom_call" in text       # the paged kernel
+    pool_dims = [",".join(map(str, d)) for d in
+                 (layer_pool, (1,) + layer_pool, (layers,) + layer_pool)]
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.+?) ([a-z-]+)\(", line)
+        if m and m.group(2) in _POOL_OPS and any(
+                f"[{d}]" in m.group(1) for d in pool_dims):
+            moved.append(line.strip()[:160])
+    assert moved == []
+    pool_bytes = 2 * math.prod(layer_pool)                      # bf16
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 
 def test_flash_attention_fwd_bwd_compiles_on_a_2x2_mesh(
