@@ -27,6 +27,12 @@ class SSMConfig:
     head_size: int = 64
     state_size: int = 16           # ssd: N per head; rwkv6 uses head_size x head_size
     chunk_size: int = 64
+    # rwkv6 (Finch) time mix: rank of the low-rank adapters of the
+    # data-dependent token shift (one per r,k,v,g,w) and of the decay, and
+    # the eps of the per-head GroupNorm (1e-5 * head_size_divisor**2)
+    mix_rank: int = 0
+    decay_rank: int = 0
+    group_norm_eps: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -91,8 +97,12 @@ class ModelConfig:
         if self.ssm is not None:
             h = d // self.ssm.head_size
             if self.ssm.kind == "rwkv6":
-                # r,k,v,g,w projections + output
-                per_layer += 5 * d * d + d * d
+                # r,k,v,g and output projections, the token-shift adapters
+                # (5 of rank mix_rank), the decay adapter, the channel
+                # mix's receptance (its key and value count as the mlp)
+                s = self.ssm
+                per_layer += 5 * d * d + 10 * d * s.mix_rank \
+                    + 2 * d * s.decay_rank + d * d
             else:  # ssd
                 per_layer += d * (2 * d + 2 * h * self.ssm.state_size + h) + d * d
         if self.moe is not None:

@@ -60,6 +60,8 @@ def build(cfg: ModelConfig, rt: Runtime, param_dtype=jnp.bfloat16) -> Model:
                                      cross=cfg.is_enc_dec),
             "final_norm": norm_init(cfg.d_model, cfg.norm),
         }
+        if cfg.family == "ssm":
+            p["ln0"] = norm_init(cfg.d_model, cfg.norm)
         if cfg.is_enc_dec:
             p["enc_layers"] = tfm.stack_init(
                 k_enc, cfg, cfg.encoder_layers, param_dtype)
@@ -67,6 +69,12 @@ def build(cfg: ModelConfig, rt: Runtime, param_dtype=jnp.bfloat16) -> Model:
         return p
 
     # ----------------------------------------------------------- helpers
+    def _ln0(params, x):
+        """rwkv6's LN0 on the embeddings, before block 0."""
+        if cfg.family != "ssm":
+            return x
+        return apply_norm(params["ln0"], x, cfg.norm)
+
     def _embed_inputs(params, batch):
         """Returns (x, positions) for the decoder stack."""
         if cfg.frontend == "vision_stub" and "embeds" in batch:
@@ -77,7 +85,8 @@ def build(cfg: ModelConfig, rt: Runtime, param_dtype=jnp.bfloat16) -> Model:
                 positions = jnp.arange(x.shape[1])[None]
             return x, positions
         tokens = batch["tokens"]
-        x = embed_tokens(params["embed"], tokens).astype(compute_dtype)
+        x = _ln0(params, embed_tokens(params["embed"], tokens)
+                 .astype(compute_dtype))
         S = x.shape[1]
         if cfg.rope == "sinusoidal":
             x = x + sinusoidal_positions(S, cfg.d_model).astype(x.dtype)
@@ -156,7 +165,7 @@ def build(cfg: ModelConfig, rt: Runtime, param_dtype=jnp.bfloat16) -> Model:
             x = embed_tokens(params["embed"], token).astype(compute_dtype)
             if cfg.rope == "sinusoidal":
                 x = x + _sinusoidal_at(pos)[:, None].astype(x.dtype)
-            return x
+            return _ln0(params, x)
 
     def decode_step(params, caches, token, pos):
         """token: (B,1) i32; pos: scalar i32 (next position to write) or a

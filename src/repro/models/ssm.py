@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.runtime import trace_names as N
 
 
 # ------------------------------------------------------------- primitive
@@ -67,7 +68,8 @@ def chunked_linear_attention(q, k, v, ld, u=None, initial_state=None,
     All heavy per-chunk work (f32 upcast, pairwise-decay intra-chunk
     attention, state recurrence) happens inside a scan over chunks, so peak
     memory is O(B*c^2*H*K) regardless of T and the inputs stay in their
-    compute dtype (bf16) outside the loop.
+    compute dtype (bf16) outside the loop. The chunk body is checkpointed,
+    so the same holds of the backward pass.
 
     factored=True (§Perf): two-level intra-chunk scheme. Cross-sub-chunk
     terms factor around the sub-chunk boundary b (s < b <= t):
@@ -170,7 +172,11 @@ def chunked_linear_attention(q, k, v, ld, u=None, initial_state=None,
             "bthk,bthv->bhkv", k_dec, vi)
         return S, o.astype(dtype)
 
-    state, o = jax.lax.scan(chunk_step, state0, (qc, kc, vc, ldc))
+    # each chunk is recomputed in the backward pass: the scan then keeps one
+    # state a chunk, not every chunk's intermediates (at rwkv6-3b's 40
+    # heads x 4096 tokens those are several GB of padded float32)
+    state, o = jax.lax.scan(jax.checkpoint(chunk_step), state0,
+                            (qc, kc, vc, ldc))
     o = jnp.moveaxis(o, 0, 1).reshape(B, n * c, H, V)[:, :T]
     return o, state
 
@@ -193,23 +199,40 @@ def linear_attention(q, k, v, ld, u=None, initial_state=None, *,
 
 
 # ------------------------------------------------------------- RWKV6 layer
+# RWKV-6 "Finch" (arXiv:2404.05892 §4; RWKV-LM's ``x060`` time mix). The
+# five token-shift mixes, in the order of the modelling code's adapter.
+MIXES = ("w", "k", "v", "r", "g")
+
+
 def rwkv6_init(key, cfg: ModelConfig, dtype) -> dict:
+    """One layer's time-mix weights. The per-channel schedules (token-shift
+    mixes ``mix_x``/``mix``, base log-log decay ``w0``) are Finch's at
+    layer 0; the adapters' second factors start small."""
     d = cfg.d_model
-    hs = cfg.ssm.head_size
+    sc = cfg.ssm
+    hs = sc.head_size
     H = d // hs
     ks = jax.random.split(key, 10)
     s = 1.0 / np.sqrt(d)
-    lora = max(32, d // 32)
+    ramp = np.arange(d) / d
+    shift = {"w": 1 - ramp, "k": 1 - ramp, "v": 1 - ramp,
+             "r": 1 - ramp ** 0.5, "g": 1 - ramp ** 0.5}
     return {
-        "mix": jnp.full((5, d), 0.5, jnp.float32),      # r,k,v,g,w token-shift mixes
+        "mix_x": jnp.asarray(1 - ramp, jnp.float32),
+        "mix": jnp.asarray(np.stack([shift[m] for m in MIXES]), jnp.float32),
+        "mix_a": (jax.random.normal(ks[8], (d, len(MIXES) * sc.mix_rank))
+                  * s).astype(dtype),
+        "mix_b": (jax.random.normal(ks[9], (len(MIXES), sc.mix_rank, d))
+                  * 0.01).astype(dtype),
         "wr": (jax.random.normal(ks[0], (d, d)) * s).astype(dtype),
         "wk": (jax.random.normal(ks[1], (d, d)) * s).astype(dtype),
         "wv": (jax.random.normal(ks[2], (d, d)) * s).astype(dtype),
         "wg": (jax.random.normal(ks[3], (d, d)) * s).astype(dtype),
         "wo": (jax.random.normal(ks[4], (d, d)) * s).astype(dtype),
-        "w0": jnp.full((d,), -6.0, jnp.float32),        # base log-log decay
-        "wa": (jax.random.normal(ks[5], (d, lora)) * s).astype(dtype),
-        "wb": (jax.random.normal(ks[6], (lora, d)) * 0.01).astype(dtype),
+        "w0": jnp.asarray(-6 + 5 * np.linspace(0, 1, d) ** 0.7, jnp.float32),
+        "wa": (jax.random.normal(ks[5], (d, sc.decay_rank)) * s).astype(dtype),
+        "wb": (jax.random.normal(ks[6], (sc.decay_rank, d)) * 0.01
+               ).astype(dtype),
         "u": (jax.random.normal(ks[7], (H, hs)) * 0.1).astype(jnp.float32),
         "ln_scale": jnp.ones((H, hs), jnp.float32),     # per-head groupnorm
         "ln_bias": jnp.zeros((H, hs), jnp.float32),
@@ -217,51 +240,76 @@ def rwkv6_init(key, cfg: ModelConfig, dtype) -> dict:
 
 
 def _token_shift(x, prev):
-    """prev: (B, d) last token of previous segment (zeros at start)."""
-    shifted = jnp.concatenate([prev[:, None], x[:, :-1]], axis=1)
-    return shifted
+    """x one token later: prev (B, d), the token before x's first (zeros
+    at a row's start), then x[:, :-1]."""
+    return jnp.concatenate([prev[:, None], x[:, :-1]], axis=1)
+
+
+def rwkv6_mix_inputs(p, x, xprev, cfg: ModelConfig):
+    """Finch's data-dependent token shift ("ddlerp"), the projections and
+    the data-dependent decay. x, xprev: (B, T, d), xprev the previous
+    token's x. Returns r, k, v (B, T, H, hs) and g (B, T, d) in x's dtype,
+    and the log decay ld = -exp(w0 + tanh(x_w A_d) B_d) (B, T, H, hs) in
+    float32."""
+    B, T, d = x.shape
+    hs, R = cfg.ssm.head_size, cfg.ssm.mix_rank
+    delta = xprev - x
+    xxx = x + delta * p["mix_x"].astype(x.dtype)
+    m = jnp.tanh(xxx @ p["mix_a"]).reshape(B, T, len(MIXES), R)
+    m = jnp.einsum("btfr,frd->fbtd", m, p["mix_b"])
+    mix = p["mix"].astype(x.dtype)
+    xw, xk, xv, xr, xg = (x + delta * (mix[i] + m[i])
+                          for i in range(len(MIXES)))
+    r = (xr @ p["wr"]).reshape(B, T, d // hs, hs)
+    k = (xk @ p["wk"]).reshape(B, T, d // hs, hs)
+    v = (xv @ p["wv"]).reshape(B, T, d // hs, hs)
+    g = jax.nn.silu(xg @ p["wg"])
+    dd = p["w0"] + (jnp.tanh(xw @ p["wa"]) @ p["wb"]).astype(jnp.float32)
+    return r, k, v, g, -jnp.exp(dd).reshape(B, T, d // hs, hs)
+
+
+def rwkv6_output(p, o, g, cfg: ModelConfig):
+    """The wkv's output o (B, T, H, hs) through the per-head GroupNorm (in
+    float32), the gate g and the output projection."""
+    B, T = o.shape[:2]
+    of = o.astype(jnp.float32)
+    mean = of.mean(-1, keepdims=True)
+    var = ((of - mean) ** 2).mean(-1, keepdims=True)
+    of = ((of - mean) * jax.lax.rsqrt(var + cfg.ssm.group_norm_eps)
+          * p["ln_scale"] + p["ln_bias"])
+    return (of.reshape(B, T, -1).astype(g.dtype) * g) @ p["wo"]
 
 
 def rwkv6_time_mix(p, x, cfg: ModelConfig, *, backend: str,
                    state=None, shift_prev=None, factored: bool = False,
                    chunk: int = None):
-    """x: (B,T,d). Returns (out, (wkv_state, last_token)).
+    """x: (B,T,d), LN1 of the residual stream. Returns (out, (wkv_state,
+    last_token)). A decode step is T = 1 with the recurrent backend and the
+    carried state and last token.
 
     chunk None = auto: pallas resolves the tuned tile, other backends use
     cfg.ssm.chunk_size; an explicit value overrides both."""
     B, T, d = x.shape
-    hs = cfg.ssm.head_size
-    H = d // hs
     prev = shift_prev if shift_prev is not None else jnp.zeros((B, d), x.dtype)
-    xx = _token_shift(x, prev)
-    mix = p["mix"].astype(x.dtype)
-    xr, xk, xv, xg, xw = (x + (xx - x) * mix[i] for i in range(5))
-    r = (xr @ p["wr"]).reshape(B, T, H, hs)
-    k = (xk @ p["wk"]).reshape(B, T, H, hs)
-    v = (xv @ p["wv"]).reshape(B, T, H, hs)
-    g = jax.nn.silu(xg @ p["wg"])
-    # data-dependent per-channel log decay (LoRA), always negative
-    ld = -jnp.exp(p["w0"] + jnp.tanh(xw @ p["wa"]) @ p["wb"])
-    ld = jnp.clip(ld, -12.0, -1e-4).reshape(B, T, H, hs)
-    if chunk is None and backend != "pallas":
-        chunk = cfg.ssm.chunk_size
-    o, new_state = linear_attention(r, k, v, ld, u=p["u"],
-                                    initial_state=state, backend=backend,
-                                    chunk=chunk, factored=factored)
-    # per-head group norm
-    of = o.astype(jnp.float32)
-    mean = of.mean(-1, keepdims=True)
-    var = ((of - mean) ** 2).mean(-1, keepdims=True)
-    of = (of - mean) * jax.lax.rsqrt(var + 1e-5) * p["ln_scale"] + p["ln_bias"]
-    out = (of.reshape(B, T, d).astype(x.dtype) * g) @ p["wo"]
+    with jax.named_scope(N.TIME_MIX):
+        r, k, v, g, ld = rwkv6_mix_inputs(p, x, _token_shift(x, prev), cfg)
+        if chunk is None and backend != "pallas":
+            chunk = cfg.ssm.chunk_size
+        with jax.named_scope(N.WKV):
+            o, new_state = linear_attention(r, k, v, ld, u=p["u"],
+                                            initial_state=state,
+                                            backend=backend, chunk=chunk,
+                                            factored=factored)
+        out = rwkv6_output(p, o, g, cfg)
     return out, (new_state, x[:, -1])
 
 
 def rwkv6_channel_mix_init(key, cfg: ModelConfig, dtype) -> dict:
     d, f = cfg.d_model, cfg.d_ff
     ks = jax.random.split(key, 3)
+    ramp = np.arange(d) / d
     return {
-        "mix": jnp.full((2, d), 0.5, jnp.float32),
+        "mix": jnp.asarray(np.stack([1 - ramp, 1 - ramp]), jnp.float32),
         "wk": (jax.random.normal(ks[0], (d, f)) / np.sqrt(d)).astype(dtype),
         "wv": (jax.random.normal(ks[1], (f, d)) / np.sqrt(f)).astype(dtype),
         "wr": (jax.random.normal(ks[2], (d, d)) / np.sqrt(d)).astype(dtype),
@@ -269,14 +317,17 @@ def rwkv6_channel_mix_init(key, cfg: ModelConfig, dtype) -> dict:
 
 
 def rwkv6_channel_mix(p, x, *, shift_prev=None):
+    """x: (B,T,d), LN2 of the residual stream: a squared-ReLU key, gated
+    by a sigmoid receptance, each on its own static token shift."""
     B, T, d = x.shape
     prev = shift_prev if shift_prev is not None else jnp.zeros((B, d), x.dtype)
-    xx = _token_shift(x, prev)
-    mix = p["mix"].astype(x.dtype)
-    xk = x + (xx - x) * mix[0]
-    xr = x + (xx - x) * mix[1]
-    kk = jnp.square(jax.nn.relu(xk @ p["wk"]))
-    return jax.nn.sigmoid(xr @ p["wr"]) * (kk @ p["wv"]), x[:, -1]
+    with jax.named_scope(N.CHANNEL_MIX):
+        delta = _token_shift(x, prev) - x
+        mix = p["mix"].astype(x.dtype)
+        xk = x + delta * mix[0]
+        xr = x + delta * mix[1]
+        kk = jnp.square(jax.nn.relu(xk @ p["wk"]))
+        return jax.nn.sigmoid(xr @ p["wr"]) * (kk @ p["wv"]), x[:, -1]
 
 
 # ------------------------------------------------------------- SSD (hymba)

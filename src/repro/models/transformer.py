@@ -372,43 +372,17 @@ def layer_decode(p, x, cache, pos, cfg: ModelConfig, rt: Runtime,
     per_row = pos.ndim > 0
     if cfg.family == "ssm":
         h_in = apply_norm(p["norm1"], x, cfg.norm)
-        B, _, d = x.shape
-        hs = cfg.ssm.head_size
-        H = d // hs
-        prev = cache["shift1"][:, None]
-        mix = p["time_mix"]["mix"].astype(x.dtype)
-        xs = [h_in + (prev - h_in) * mix[i] for i in range(5)]
-        xr, xk, xv, xg, xw = xs
-        tm = p["time_mix"]
-        r = (xr @ tm["wr"]).reshape(B, H, hs)
-        k = (xk @ tm["wk"]).reshape(B, H, hs)
-        v = (xv @ tm["wv"]).reshape(B, H, hs)
-        g = jax.nn.silu(xg @ tm["wg"])[:, 0]
-        ld = -jnp.exp(tm["w0"] + jnp.tanh(xw @ tm["wa"]) @ tm["wb"])
-        ld = jnp.clip(ld, -12.0, -1e-4).reshape(B, H, hs)
-        state, o = ssm_mod.linear_attention_step(
-            cache["wkv_state"], r.astype(jnp.float32), k.astype(jnp.float32),
-            v.astype(jnp.float32), ld.astype(jnp.float32), tm["u"])
-        of = o.astype(jnp.float32)
-        mean = of.mean(-1, keepdims=True)
-        var = ((of - mean) ** 2).mean(-1, keepdims=True)
-        of = ((of - mean) * jax.lax.rsqrt(var + 1e-5) * tm["ln_scale"]
-              + tm["ln_bias"])
-        h = (of.reshape(B, d).astype(x.dtype) * g) @ tm["wo"]
-        x = x + h[:, None]
+        h, (state, _) = ssm_mod.rwkv6_time_mix(
+            p["time_mix"], h_in, cfg, backend="recurrent",
+            state=cache["wkv_state"], shift_prev=cache["shift1"])
+        x = x + h
         new_cache["wkv_state"] = state
         new_cache["shift1"] = h_in[:, 0]
-        # channel mix
         h_in = apply_norm(p["norm2"], x, cfg.norm)
-        cmix = p["channel_mix"]["mix"].astype(x.dtype)
-        prev = cache["shift2"][:, None]
-        xk_ = h_in + (prev - h_in) * cmix[0]
-        xr_ = h_in + (prev - h_in) * cmix[1]
-        cm = p["channel_mix"]
-        kk = jnp.square(jax.nn.relu(xk_ @ cm["wk"]))
-        x = x + jax.nn.sigmoid(xr_ @ cm["wr"]) * (kk @ cm["wv"])
+        h, _ = ssm_mod.rwkv6_channel_mix(p["channel_mix"], h_in,
+                                         shift_prev=cache["shift2"])
         new_cache["shift2"] = h_in[:, 0]
-        return x, new_cache
+        return x + h, new_cache
 
     h_in = apply_norm(p["norm1"], x, cfg.norm)
     with jax.named_scope(ATTENTION):

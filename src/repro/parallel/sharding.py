@@ -102,8 +102,9 @@ def param_pspecs(params_shape, cfg: ModelConfig, rcfg: RunConfig):
                                                "model") else None
                 return P(fsdp, vshard)
         # ---- norms / scalars / vectors ----
-        if nd - len(L) <= 1 or name in ("mix", "u", "ln_scale", "ln_bias",
-                                        "w0", "dt_bias", "A_log", "D"):
+        if nd - len(L) <= 1 or name in ("mix", "mix_b", "u", "ln_scale",
+                                        "ln_bias", "w0", "dt_bias", "A_log",
+                                        "D"):
             if name in ("bq", "bk", "bv") and not in_ssm:
                 return spec("model")
             return P(*((None,) * nd))

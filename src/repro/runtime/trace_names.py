@@ -54,6 +54,10 @@ LM_HEAD = "lm_head"          # logits over the vocabulary
 LOSS = "loss"                # log-softmax and mean NLL
 SAMPLE = "sample"            # sampling and lane bookkeeping of a decode step
 OPTIMIZER = "optimizer"      # AdamW update and the global gradient norm
+TIME_MIX = "time_mix"        # rwkv6: token shift, projections, decay,
+                             # GroupNorm and output projection
+WKV = "wkv"                  # rwkv6, inside TIME_MIX: the recurrence alone
+CHANNEL_MIX = "channel_mix"  # rwkv6: the squared-ReLU channel mix
 
 SCOPES = (EMBED, LAYERS, NORM, ATTENTION, KV_WRITE, MLP, MOE, LM_HEAD, LOSS,
-          SAMPLE, OPTIMIZER)
+          SAMPLE, OPTIMIZER, TIME_MIX, WKV, CHANNEL_MIX)

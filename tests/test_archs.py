@@ -119,6 +119,9 @@ def test_full_configs_match_assignment():
     assert b.moe.dense_residual_ff == 4864
     c = ARCHS["rwkv6-3b"]
     assert c.attention_kind == "none" and c.ssm.kind == "rwkv6"
+    # Finch's adapter ranks and GroupNorm eps (1e-5 * head_size_divisor**2)
+    assert (c.ssm.mix_rank, c.ssm.decay_rank) == (32, 64)
+    assert c.ssm.group_norm_eps == pytest.approx(6.4e-4)
     d = ARCHS["hymba-1.5b"]
     assert d.ssm is not None and d.attention_kind == "sliding"
     w = ARCHS["whisper-large-v3"]
