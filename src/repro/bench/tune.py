@@ -47,7 +47,7 @@ SUBLANE = 8                    # min f32 sublane tile
 _ATTN_BLOCKS = (128, 256, 512)
 _WKV_CHUNKS = (16, 32, 64, 128, 256)
 _NORM_ROWS = (64, 128, 256, 512, 1024)
-_PAGED_PPB = (1, 2, 4, 8)
+_PAGED_PPB = (4, 8, 16, 32)
 
 
 def attention_vmem_bytes(bq: int, bk: int, D: int, itemsize: int) -> int:
@@ -73,13 +73,18 @@ def rmsnorm_vmem_bytes(br: int, d: int, itemsize: int) -> int:
     return 2 * blocks + f32_tmp
 
 
-def paged_vmem_bytes(ppb: int, ps: int, g: int, D: int,
+def paged_vmem_bytes(ppb: int, ps: int, hkv: int, g: int, D: int,
                      itemsize: int) -> int:
-    """q/o tiles plus ``pages_per_block`` double-buffered K and V page
-    DMAs; online-softmax scratch is f32."""
-    blocks = (2 * g * D + 2 * ppb * ps * D) * itemsize
-    scratch = (2 * g + g * D) * 4                        # m, l, acc
-    return 2 * blocks + scratch
+    """The K/V pools stay in HBM: two VMEM slots of ``pages_per_block``
+    pages (ps, Hkv, D) a side, the q/o row tiles double-buffered, the f32
+    online-softmax scratch, and the f32 block a step scores (K and V
+    cast, the scores and their exponentials)."""
+    hq, cols = hkv * g, ppb * ps * hkv
+    slots = 2 * 2 * ppb * ps * hkv * D * itemsize
+    rows = 2 * 2 * hq * D * itemsize                     # q, o
+    scratch = (2 * hq + hq * D) * 4                      # m, l, acc
+    work = (2 * cols * D + 2 * hq * cols) * 4            # k, v, s, pe
+    return slots + rows + scratch + work
 
 
 def _budget(vmem_budget: Optional[int]) -> int:
@@ -174,8 +179,8 @@ def rmsnorm_candidates(rows: int, d: int, itemsize: int,
                            else None)
 
 
-def paged_candidates(n_pages: int, ps: int, g: int, D: int, itemsize: int,
-                     vmem_budget: Optional[int] = None
+def paged_candidates(n_pages: int, ps: int, hkv: int, g: int, D: int,
+                     itemsize: int, vmem_budget: Optional[int] = None
                      ) -> Tuple[List[Dict[str, int]], int,
                                 Optional[Dict[str, int]]]:
     budget = _budget(vmem_budget)
@@ -185,12 +190,13 @@ def paged_candidates(n_pages: int, ps: int, g: int, D: int, itemsize: int,
     for ppb in _PAGED_PPB:
         if ppb > n_pages:
             continue
-        if paged_vmem_bytes(ppb, ps, g, D, itemsize) > budget:
+        if paged_vmem_bytes(ppb, ps, hkv, g, D, itemsize) > budget:
             rejected += 1
             continue
         if ppb != default_p:
             out.append({"pages_per_block": ppb})
-    default_ok = paged_vmem_bytes(default_p, ps, g, D, itemsize) <= budget
+    default_ok = paged_vmem_bytes(default_p, ps, hkv, g, D,
+                                  itemsize) <= budget
     if default_ok:
         out.insert(0, {"pages_per_block": default_p})
     return out, rejected, ({"pages_per_block": default_p} if default_ok
@@ -375,7 +381,7 @@ def tune_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     npag = block_tables.shape[1]
     sig = tuning.paged_attention_signature(q.shape, k_pages.shape, npag,
                                            q.dtype)
-    cands, rej, dflt = paged_candidates(npag, ps, Hq // Hkv, D,
+    cands, rej, dflt = paged_candidates(npag, ps, Hkv, Hq // Hkv, D,
                                         q.dtype.itemsize, vmem_budget)
 
     def make_fn(pages_per_block: int):

@@ -126,7 +126,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
     """Block-table paged decode attention (no backward: decode only) over
     the stacked pools (L, P, page_size, Hkv, D) at ``layer``, or over one
     layer's pool (P, page_size, Hkv, D). pages_per_block None = auto
-    (tuned cache -> 1)."""
+    (tuned cache -> default)."""
     ppb = tuning.resolve_paged_pages_per_block(
         pages_per_block, q_shape=q.shape, pages_shape=k_pages.shape[-4:],
         n_pages=block_tables.shape[1], dtype=q.dtype)

@@ -42,7 +42,7 @@ DEFAULTS: Dict[str, Dict[str, int]] = {
     "flash_attention_bwd": {"block_q": 128, "block_k": 128},
     "wkv6_fwd": {"chunk": 64},
     "rmsnorm_fwd": {"block_rows": 256},
-    "paged_attention_fwd": {"pages_per_block": 1},
+    "paged_attention_fwd": {"pages_per_block": 8},
 }
 
 # env-fingerprint keys that must match for a cache file to be trusted

@@ -483,11 +483,14 @@ def layer_decode_paged(p, x, pools, layer, pos, block_tables,
     """Single-token step against the paged KV pool. x: (B,1,d); pools:
     the stacked {"k","v"} pools (L, P, page_size, Hkv, D) — no batch
     axis — of which this is layer ``layer``; pos: (B,) per-row positions;
-    block_tables: (B, n_pages) physical page ids in logical order
-    (retired rows all-null). Each row writes its new K/V at (layer,
-    table[pos // page_size], pos % page_size) — rows own disjoint pages,
-    so the scatter never races — and the kernel reads the same stacked
-    pool at ``layer``: no layer's pool is sliced out or written back."""
+    block_tables: (B, n_pages) physical page ids in logical order. A
+    retired row's table is all null, and the Pallas kernel relies on
+    that: it walks no page of a row whose first entry is the null page,
+    whatever stale position the row keeps. Each row writes its new K/V
+    at (layer, table[pos // page_size], pos % page_size) — rows own
+    disjoint pages, so the scatter never races — and the kernel reads
+    the same stacked pool at ``layer``: no layer's pool is sliced out or
+    written back."""
     pos = jnp.asarray(pos)
     h_in = apply_norm(p["norm1"], x, cfg.norm)
     with jax.named_scope(ATTENTION):

@@ -220,7 +220,7 @@ def test_paged_oversized_pages_per_block_rejected():
     findings = kernel_lint.check_config(
         "paged_attention_fwd", dims, {"pages_per_block": 512}, "tpu"
     )
-    assert "RK002" in {f.rule for f in findings}  # 1024 page DMAs resident at once
+    assert "RK002" in {f.rule for f in findings}  # 2 slots x 512 pages a side
 
 
 def test_paged_default_accepted():
@@ -237,23 +237,30 @@ def test_paged_default_accepted():
 
 @pytest.mark.parametrize("layers", [1, 10])
 def test_paged_plan_reads_the_stacked_pool_at_its_layer(layers):
-    """The plan mirrors the shipped kernel: K/V blocks of one page of one
-    layer on the stacked (L, P, ps, Hkv, D) pool, addressed through the
-    layer and block-table scalar operands, and RK004 sees the layer axis."""
+    """The plan mirrors the shipped kernel: one grid step per row, the
+    stacked (L, P, ps, Hkv, D) pools left in HBM and copied a page of one
+    layer at a time, addressed through the layer and block-table scalar
+    operands (RK004 sees the layer axis), into two VMEM slots of
+    pages_per_block pages a side, which RK002 charges instead of the
+    pools."""
     dims = dict(
         B=4, Hq=32, Hkv=8, D=128, L=layers, P=64, ps=16, npag=8, dtype="float32"
     )
     (plan,) = kernel_lint.plan_paged_attention(dims, {"pages_per_block": 2})
-    pages = [b for b in plan.blocks if "_pages[" in b.name]
-    assert len(pages) == 4
+    assert plan.grid == (4,)
+    pages = [b for b in plan.blocks if b.name.endswith("_pages")]
+    assert [b.name for b in pages] == ["k_pages", "v_pages"]
     for blk in pages:
+        assert blk.hbm
         assert blk.array_shape == (layers, 64, 16, 8, 128)
         assert blk.block_shape == (1, 1, 16, 8, 128)
-        assert blk.index_map(3, 3) == (layers - 1, 63, 0, 0, 0)
+        assert blk.index_map(3) == (layers - 1, 63, 0, 0, 0)
+    slots = {name: shape for name, shape, _ in plan.scratch}
+    assert slots["k_slots"] == slots["v_slots"] == (2, 2, 16, 8, 128)
     caps = tuning.capabilities("tpu")
     assert kernel_lint._check_plan(plan, caps) == []
     inner = pages[0].index_map
-    pages[0].index_map = lambda b, j: (inner(b, j)[0] + 1,) + inner(b, j)[1:]
+    pages[0].index_map = lambda b: (inner(b)[0] + 1,) + inner(b)[1:]
     rules = {f.rule for f in kernel_lint._check_plan(plan, caps)}
     assert rules == {"RK004"}
 

@@ -114,24 +114,103 @@ def test_allocator_random_admit_retire(ops, page_size):
 
 
 # ---------------------------------------------------------------- kernel
-@pytest.mark.parametrize("ppb", [1, 2, 3, 4])
+def _kernel_inputs(seed, *, B, Hq, Hkv, D, L, P, ps, npag):
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.standard_normal((B, 1, Hq, D)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((L, P, ps, Hkv, D)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((L, P, ps, Hkv, D)), jnp.float32)
+    bt = jnp.asarray(rng.integers(1, P, size=(B, npag)), jnp.int32)
+    return q, kp, vp, bt
+
+
+@pytest.mark.parametrize("ppb", [1, 2, 3, 4, 8])
 def test_paged_kernel_matches_reference(ppb):
     """The in-kernel block-table gather must match the gather-then-
     decode_attention reference for every pages_per_block tiling,
-    including one that does not divide the table width (null-page
-    padding)."""
-    rng = np.random.default_rng(0)
-    B, Hq, Hkv, D, P, ps, npag = 3, 4, 2, 16, 9, 4, 4
-    q = jnp.asarray(rng.standard_normal((B, 1, Hq, D)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((P, ps, Hkv, D)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((P, ps, Hkv, D)), jnp.float32)
-    bt = jnp.asarray(rng.integers(1, P, size=(B, npag)), jnp.int32)
-    lens = jnp.asarray([5, 16, 1], jnp.int32)
-    ref = paged_decode_attention_ref(q, kp, vp, bt, lens)
-    out = paged_attention_fwd(q, kp, vp, bt, lens, pages_per_block=ppb,
-                              interpret=True)
+    including ones that do not divide the table width (a last, partial
+    block), on the stacked pool at a nonzero layer, for lengths of one
+    token, mid-page, on a page edge and the full table."""
+    npag, ps = 10, 4
+    q, kp, vp, bt = _kernel_inputs(0, B=4, Hq=4, Hkv=2, D=16, L=3, P=23,
+                                   ps=ps, npag=npag)
+    lens = jnp.asarray([1, 6, 8, npag * ps], jnp.int32)
+    layer = jnp.int32(2)
+    ref = paged_decode_attention_ref(q, kp, vp, bt, lens, layer)
+    out = paged_attention_fwd(q, kp, vp, bt, lens, layer,
+                              pages_per_block=ppb, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("g", [1, 2, 4])
+def test_paged_kernel_group_sizes(g):
+    """Each query head of a group of g scores only its own KV head's
+    rows of the flattened page block: MHA (g = 1) and GQA alike."""
+    Hkv = 2
+    q, kp, vp, bt = _kernel_inputs(5, B=3, Hq=g * Hkv, Hkv=Hkv, D=16, L=2,
+                                   P=13, ps=4, npag=5)
+    lens = jnp.asarray([3, 12, 20], jnp.int32)
+    ref = paged_decode_attention_ref(q, kp, vp, bt, lens, jnp.int32(1))
+    out = paged_attention_fwd(q, kp, vp, bt, lens, jnp.int32(1),
+                              pages_per_block=3, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("ppb", [2, 3])
+def test_paged_kernel_reads_only_live_pages(ppb):
+    """A row reads only its live pages, cdiv(length, page_size) of them,
+    and a retired row (its table all null) reads nothing and scores
+    nothing, whatever stale length it carries.
+
+    Every page a live row's table names past its live pages, the null
+    page and the tokens past each live row's length hold NaN: live rows
+    still equal the reference on the clean pool, and retired rows are
+    zero. Run by the TPU interpreter, which raises on a read outside a
+    buffer: page ids outside the pool past the live pages, and in a
+    retired row's table, change nothing, while one among a row's live
+    pages is read and refused."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, ps, npag, P, layer = 6, 4, 5, 24, jnp.int32(1)
+    q, kp, vp, _ = _kernel_inputs(11, B=B, Hq=4, Hkv=2, D=16, L=2, P=P,
+                                  ps=ps, npag=npag)
+    lens = np.asarray([1, 6, 8, 20, 13, 20], np.int32)
+    live = lens.copy()
+    live[4:] = 0                                   # rows 4, 5 retired
+    n_live = -(-live // ps)
+    bt = np.zeros((B, npag), np.int32)
+    bt[:4] = np.arange(1, 4 * npag + 1).reshape(4, npag)   # distinct pages
+    ref = paged_decode_attention_ref(q, kp, vp, jnp.asarray(bt),
+                                     jnp.asarray(lens), layer)
+    kpn, vpn = np.array(kp), np.array(vp)
+    for b in range(4):
+        for pool in (kpn, vpn):
+            pool[:, bt[b, n_live[b]:]] = np.nan
+            last = bt[b, n_live[b] - 1]
+            pool[:, last, lens[b] - (n_live[b] - 1) * ps:] = np.nan
+    kpn[:, 0] = vpn[:, 0] = np.nan                 # the null page
+
+    def run(table, lengths=lens):
+        return np.asarray(paged_attention_fwd(
+            q, jnp.asarray(kpn), jnp.asarray(vpn), jnp.asarray(table),
+            jnp.asarray(lengths), layer, pages_per_block=ppb,
+            interpret=pltpu.InterpretParams()))
+
+    out = run(bt)
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out[:4], np.asarray(ref)[:4],
+                               atol=2e-5, rtol=1e-4)
+    np.testing.assert_array_equal(out[4:], 0.0)
+    outside = bt.copy()
+    for b in range(B):
+        first = max(n_live[b], 1)
+        outside[b, first:] = P + 100 + np.arange(npag - first)
+    np.testing.assert_array_equal(run(outside), out)
+    longer = lens.copy()
+    longer[0] = 2 * ps                             # page 1 of row 0: live
+    with pytest.raises(Exception):
+        run(outside, longer)
 
 
 def test_paged_tuning_wiring():
@@ -141,14 +220,14 @@ def test_paged_tuning_wiring():
     from repro.kernels import ops
 
     sig_args = dict(q_shape=(2, 1, 4, 16), pages_shape=(8, 4, 2, 16),
-                    n_pages=4, dtype=np.float32)
+                    n_pages=16, dtype=np.float32)
     assert tuning.resolve_paged_pages_per_block(None, **sig_args) == \
-        tuning.DEFAULTS["paged_attention_fwd"]["pages_per_block"]
+        tuning.DEFAULTS["paged_attention_fwd"]["pages_per_block"] == 8
     assert tuning.resolve_paged_pages_per_block(4, **sig_args) == 4
     cands, rejected, default = paged_candidates(
-        n_pages=8, ps=16, g=2, D=64, itemsize=4)
-    assert default == {"pages_per_block": 1} and cands[0] == default
-    assert {c["pages_per_block"] for c in cands} <= {1, 2, 4, 8}
+        n_pages=64, ps=16, hkv=2, g=2, D=64, itemsize=4)
+    assert default == {"pages_per_block": 8} and cands[0] == default
+    assert {c["pages_per_block"] for c in cands} == {4, 8, 16, 32}
     rng = np.random.default_rng(1)
     q = jnp.asarray(rng.standard_normal((2, 1, 4, 16)), jnp.float32)
     kp = jnp.asarray(rng.standard_normal((8, 4, 2, 16)), jnp.float32)
@@ -422,6 +501,38 @@ def test_paged_engine_parity_mixed_prompt_lengths():
     assert rep_p.completed == 3
     assert rep_p.page_occupancy_peak > 0
     assert 0.0 <= rep_p.fragmentation_mean < 1.0
+
+
+def test_paged_engine_pallas_matches_reference_backend():
+    """The paged engine on the (interpret-mode) Pallas kernel emits the
+    very token streams of the gather reference, with lanes retiring
+    mid-run: a retired lane keeps its stale position and an all-null
+    table, and later requests reuse lanes and pages."""
+    from repro.models import transformer as tfm
+    from repro.models.model import build
+
+    cfg = reduced(ARCHS["granite-3-8b"], layers=2, d_model=64, vocab=128,
+                  d_ff=128)
+    rng = np.random.default_rng(6)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n).astype(np.int32)
+               for n in (9, 3, 6, 11, 5)]
+    budgets = (2, 7, 3, 5, 6)
+    streams = []
+    for backend in ("dense", "pallas"):
+        model = build(cfg, tfm.Runtime(attention_backend=backend,
+                                       paged_pages_per_block=2),
+                      param_dtype=jnp.float32)
+        params = model.init_params(jax.random.PRNGKey(2))
+        eng = PagedEngine(model.prefill_chunk, model.decode_step_paged,
+                          params, model.paged_cache_init, slots=3,
+                          cache_span=24, page_size=4,
+                          prefill_chunk_tokens=4, clock=SimClock())
+        rep = eng.run([Request(i, p, n) for i, (p, n)
+                       in enumerate(zip(prompts, budgets))])
+        assert rep.completed == len(prompts)
+        streams.append([list(m.tokens) for m in rep.metrics])
+    assert [len(t) for t in streams[1]] == list(budgets)
+    assert streams[1] == streams[0]
 
 
 # ------------------------------------------ stub engines (scheduling only)
